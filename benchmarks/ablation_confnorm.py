@@ -61,4 +61,7 @@ def run(n: int = 2000) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.mesh import use_compile_cache
+
+    use_compile_cache()
     run()

@@ -3,7 +3,8 @@
 Two views, because this container has one physical core:
   * real multi-device wall time via a subprocess per M (XLA host devices;
     same-core contention makes absolute speedups flat, so this validates
-    *runnability*, not speedup);
+    *runnability*, not speedup). Each child is a CPU rehearsal, pinned to
+    ``JAX_PLATFORMS=cpu`` so it never competes for a chip;
   * the parallel-critical-path proxy: max per-node verification load from
     the single-host executor sharded M ways — the quantity whose M-scaling
     the paper's Fig. 10 actually demonstrates.
@@ -11,6 +12,7 @@ Two views, because this container has one physical core:
 from __future__ import annotations
 
 import json
+import os
 import subprocess
 import sys
 
@@ -52,7 +54,8 @@ def run(n: int = 1600, p: int = 16) -> None:
         out = subprocess.run(
             [sys.executable, "-c", _SUB.format(m=m, n=n, delta=delta, p=p)],
             capture_output=True, text=True, timeout=1200,
-            env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root"},
+            env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+                 "HOME": os.environ.get("HOME", "."), "JAX_PLATFORMS": "cpu"},
             cwd=".",
         )
         assert out.returncode == 0, out.stderr[-2000:]
@@ -63,4 +66,7 @@ def run(n: int = 1600, p: int = 16) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.mesh import use_compile_cache
+
+    use_compile_cache()
     run()

@@ -43,4 +43,7 @@ def run(n: int = 1200, k: int = 256, p: int = 12) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.mesh import use_compile_cache
+
+    use_compile_cache()
     run()

@@ -5,6 +5,9 @@ Sections (``--rs`` adds a fourth):
 1. distributed — per-arm wall time of the 8-device shard_map pipeline
    (real wall clock; base / tighten / p-sweep / noprune arms), run in a
    subprocess so the device-count flag never leaks into the parent process.
+   Every 8-device section (1, 3's distributed arm, 4 and 6) is a CPU
+   rehearsal: its child runs with ``JAX_PLATFORMS=cpu`` on virtual host
+   devices, never on a chip, and its times are CPU times.
    Each arm reports its pivot-filter pruning rate (fraction of candidate
    pairs skipping exact evaluation) and exact-evaluation count.
 2. verify-engine — the reduce-phase hot spot head-to-head: the seed's dense
@@ -213,11 +216,12 @@ print(json.dumps(out))
 
 
 def _run_sub(prog: str):
+    """Run one 8-virtual-device section in a child process. The child is a
+    CPU rehearsal of the mesh path: it is pinned to ``JAX_PLATFORMS=cpu`` so
+    it never competes with a parent, or another process, for a chip."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = {"PYTHONPATH": os.path.join(root, "src"), "PATH": "/usr/bin:/bin",
-           "HOME": os.environ.get("HOME", "/root")}
-    if os.environ.get("JAX_PLATFORMS"):
-        env["JAX_PLATFORMS"] = os.environ["JAX_PLATFORMS"]
+           "HOME": os.environ.get("HOME", root), "JAX_PLATFORMS": "cpu"}
     res = subprocess.run(
         [sys.executable, "-c", prog], capture_output=True, text=True,
         timeout=1800, env=env, cwd=root,
@@ -613,5 +617,8 @@ if __name__ == "__main__":
                     help="also run the asymmetric R×S cross-join arm "
                          "(|R| = n/5 vs |S| = n, exactness-checked)")
     args = ap.parse_args()
+    from repro.launch.mesh import use_compile_cache
+
+    use_compile_cache()
     run(n=args.n, delta=args.delta, n_verify=args.n_verify, smoke=args.smoke,
         rs=args.rs)

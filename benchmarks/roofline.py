@@ -158,4 +158,7 @@ def run() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.mesh import use_compile_cache
+
+    use_compile_cache()
     run()

@@ -16,6 +16,7 @@ from benchmarks import (
     fig10_scaleout, fig11_scaleup, fig12_verifications, table3_balance,
     roofline, serve_qps,
 )
+from repro.launch.mesh import use_compile_cache
 
 MODULES = {
     "fig6": lambda q: fig6_techniques.run(n=800 if q else 1200),
@@ -36,6 +37,7 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--only", default=None, help="comma-separated module keys")
     args = ap.parse_args()
+    use_compile_cache()
 
     keys = args.only.split(",") if args.only else list(MODULES)
     failures = []

@@ -47,6 +47,7 @@ from benchmarks.common import Csv, OUT_DIR
 from repro.core import index as index_lib
 from repro.core import mapping, partition, spjoin
 from repro.data import synthetic
+from repro.launch.mesh import use_compile_cache
 
 # Control-plane entry points the BUILD phase owns. Each is patched at its
 # defining module, and every call site reaches it through module-attribute
@@ -233,6 +234,7 @@ def main() -> None:
     ap.add_argument("--n-queries", type=int, default=4096)
     ap.add_argument("--batch", type=int, default=256)
     args = ap.parse_args()
+    use_compile_cache()
     run(n=args.n, n_queries=args.n_queries, batch=args.batch, smoke=args.smoke)
 
 

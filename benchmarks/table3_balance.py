@@ -10,7 +10,8 @@ distributed executor's per-DEVICE loads: the contiguous cell→device layout
 vs the cost-model-guided LPT plan (``core.placement``) on a skewed mixture,
 8 simulated devices — the paper's Table 3 balance story, finally measured
 at placement granularity. Run in a subprocess so the device-count flag
-never leaks into the parent.
+never leaks into the parent; the child is a CPU rehearsal
+(``JAX_PLATFORMS=cpu`` on virtual host devices), never a chip run.
 """
 from __future__ import annotations
 
@@ -75,10 +76,9 @@ def run(n: int = 1200, k: int = 256, p: int = 12) -> None:
 
     # Distributed arm: per-DEVICE balance, contiguous vs LPT placement.
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    # CPU rehearsal on 8 virtual host devices, pinned off any chip.
     env = {"PYTHONPATH": os.path.join(root, "src"), "PATH": "/usr/bin:/bin",
-           "HOME": os.environ.get("HOME", "/root")}
-    if os.environ.get("JAX_PLATFORMS"):
-        env["JAX_PLATFORMS"] = os.environ["JAX_PLATFORMS"]
+           "HOME": os.environ.get("HOME", root), "JAX_PLATFORMS": "cpu"}
     res = subprocess.run(
         [sys.executable, "-c", _SUB_DIST.format(n=n)],
         capture_output=True, text=True, timeout=1800, env=env, cwd=root,
@@ -95,4 +95,7 @@ def run(n: int = 1200, k: int = 256, p: int = 12) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.mesh import use_compile_cache
+
+    use_compile_cache()
     run()

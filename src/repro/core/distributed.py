@@ -64,7 +64,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.core import cost_model, distances, expfam, gof, mapping, partition, sampling
 from repro.core import placement as placement_lib
 from repro.core import verify as verify_lib
@@ -126,7 +125,7 @@ def make_stage_stats(
         count_all = jax.lax.all_gather(my_count, axis)  # (M,)
         return packets, conf_all, count_all
 
-    shmap = compat.shard_map(
+    shmap = jax.shard_map(
         per_shard,
         mesh=mesh,
         in_specs=(P(axis), P(axis)),
@@ -305,7 +304,7 @@ def make_stage_counts(
             jax.lax.all_gather(hi, axis),
         )
 
-    shmap = compat.shard_map(
+    shmap = jax.shard_map(
         per_shard, mesh=mesh, in_specs=(P(axis), P(axis)),
         out_specs=(P(), P(), P(), P()),
         check_vma=False,
@@ -422,21 +421,21 @@ def _make_w_dispatch(rt: _RoutingTables, cap_w: int):
         slot_ok = member_d & (w_rank < cap_w)
         cc = jnp.where(slot_ok, jnp.arange(n_slots)[None, :], n_slots)
         rr = jnp.clip(w_rank, 0, cap_w - 1)
-        w_buf = (
-            jnp.zeros((n_slots, cap_w, x.shape[-1]), x.dtype)
+        # Scatter row NUMBERS into the slot table, then gather the rows: a
+        # payload scatter would broadcast every row to every slot first, an
+        # (n_loc, n_slots, m) update that outgrows HBM at real sizes. Row
+        # n_loc is the padding row (zeros, id -1, cell -1).
+        n_loc = x.shape[0]
+        src = (
+            jnp.full((n_slots, cap_w), n_loc, jnp.int32)
             .at[cc, rr]
-            .set(x[:, None, :], mode="drop")
+            .set(jnp.broadcast_to(jnp.arange(n_loc, dtype=jnp.int32)[:, None], cc.shape),
+                 mode="drop")
         )
-        w_ids = (
-            jnp.full((n_slots, cap_w), -1, jnp.int32)
-            .at[cc, rr]
-            .set(jnp.broadcast_to(ids.astype(jnp.int32)[:, None], cc.shape), mode="drop")
-        )
-        w_own = (
-            jnp.full((n_slots, cap_w), -1, jnp.int32)
-            .at[cc, rr]
-            .set(jnp.broadcast_to(cells[:, None], cc.shape), mode="drop")
-        )
+        w_buf = jnp.concatenate([x, jnp.zeros((1, x.shape[-1]), x.dtype)])[src]
+        pad = jnp.full((1,), -1, jnp.int32)
+        w_ids = jnp.concatenate([ids.astype(jnp.int32), pad])[src]
+        w_own = jnp.concatenate([cells.astype(jnp.int32), pad])[src]
         overflow_w = (member_d & (w_rank >= cap_w)).sum()
         return w_buf, w_ids, w_own, overflow_w
 
@@ -480,7 +479,8 @@ class VerifyConfig:
     emit_pairs: bool = False  # also return hit masks + id buffers (tests)
     emit: str = "mask"  # pair-emission path when emit_pairs: "mask" returns
     #   the per-slot hit masks + id buffers; "compact" compacts each slot's
-    #   hits in-trace (ref.compact_mask under vmap) into a static
+    #   hits in-trace, one slot at a time (ref.compact_mask under
+    #   lax.map, so one slot's mask is live), into a static
     #   (pair_cap, 2) global-id buffer + true-count — an output-sensitive
     #   stage OUTPUT, not a new collective: the pairs ride the stage's
     #   existing out_specs, the all_to_all budget is unchanged. A count
@@ -598,10 +598,24 @@ def make_stage_verify(
                 n_cand = n_verified
             return mask, n_verified, n_cand
 
-        masks, n_verified, n_cand = jax.vmap(verify_cell)(
-            fv, fvi, fvo, fw, fwi, fwo, local_cells
-        )
-        hit_count = masks.sum()
+        slots = (fv, fvi, fvo, fw, fwi, fwo, local_cells)
+        if emit == "compact":
+            # One slot at a time: only that slot's (M·cap_v, M·cap_w) mask
+            # is ever live, and only its compacted pairs leave the loop —
+            # under vmap every slot's mask would sit in HBM at once.
+            # The masks are already validity- and de-dup-filtered
+            # (verify_tile -> ref.emit_mask), so compaction just gathers
+            # global ids.
+            def compact_slot(slot):
+                mask, n_ver, n_cnd = verify_cell(*slot)
+                pairs, count = kref.compact_mask(mask, slot[1], slot[4], vcfg.pair_cap)
+                return pairs, count, n_ver, n_cnd
+
+            cpairs, ccounts, n_verified, n_cand = jax.lax.map(compact_slot, slots)
+            hit_count = ccounts.sum()
+        else:
+            masks, n_verified, n_cand = jax.vmap(verify_cell)(*slots)
+            hit_count = masks.sum()
         out = {
             "hits": hit_count.astype(jnp.float32)[None],
             "verified": n_verified.sum().astype(jnp.float32)[None],
@@ -613,13 +627,6 @@ def make_stage_verify(
         }
         if vcfg.emit_pairs:
             if emit == "compact":
-                # Per-slot on-device compaction: masks are already validity-
-                # and de-dup-filtered (verify_tile -> ref.emit_mask), so the
-                # compaction just gathers global ids. Pure jnp, vmap-safe —
-                # the kernel dispatch and collective budget are untouched.
-                cpairs, ccounts = jax.vmap(
-                    lambda mk, vi, wi: kref.compact_mask(mk, vi, wi, vcfg.pair_cap)
-                )(masks, fvi, fwi)
                 out["pairs"] = cpairs  # (spd, pair_cap, 2) int32, -1 padded
                 out["pair_counts"] = ccounts  # (spd,) int32 TRUE totals
             else:
@@ -677,7 +684,7 @@ def make_stage_verify(
         else:
             out_specs.update({"masks": P(axis), "v_ids": P(axis), "w_ids": P(axis)})
 
-    shmap = compat.shard_map(
+    shmap = jax.shard_map(
         per_shard,
         mesh=mesh,
         in_specs=in_specs,
@@ -733,6 +740,10 @@ class DistJoinResult:
     #   (after capability resolution and any overflow fallback)
     n_overflow_retries: int = 0  # compact-emission stage re-runs forced by
     #   the overflow sentinel (same counter semantics as VerifyStats)
+    backend: str = "numpy"  # resolved kernel backend of every stage
+    map_fused: bool = True  # the stages mapped with the single-pass map kernel
+    device_rows: np.ndarray | None = None  # (M,) V rows each device holds
+    #   after the shuffle (dispatched, not padded)
 
 
 def _pad_shard_set(x: Array, M: int, sharding) -> tuple[Array, Array, Array, int]:
@@ -1102,6 +1113,11 @@ def distributed_join(
         capacity_saved_bytes=int(cap_saved),
         emit=vcfg.emit if emit_pairs else "mask",
         n_overflow_retries=n_overflow_retries,
+        backend=backend,
+        map_fused=map_fused,
+        device_rows=np.bincount(
+            pl.device_of_slot, weights=v_slot.sum(0), minlength=M
+        ).astype(np.int64),
     )
 
 
@@ -1187,7 +1203,7 @@ def make_stage_serve(
             "overflow": overflow.astype(jnp.float32)[None],
         }
 
-    shmap = compat.shard_map(
+    shmap = jax.shard_map(
         per_shard,
         mesh=mesh,
         in_specs=(P(axis),) * 5,

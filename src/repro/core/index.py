@@ -977,12 +977,7 @@ def build_index(
 def brute_force_query(
     index_data: np.ndarray, q: np.ndarray, delta: float, metric: str
 ) -> np.ndarray:
-    """Oracle for tests/benchmarks: (i ∈ R, j ∈ Q) pairs from the dense
-    cross-distance matrix — the parity target of ``query_batch``."""
-    mask = np.asarray(
-        distances.brute_force_join(
-            jnp.asarray(index_data), jnp.asarray(q), delta, metric
-        )
-    )
-    i, j = np.nonzero(mask)
-    return np.stack([i, j], axis=1).astype(np.int64)
+    """Oracle for tests/benchmarks: (i ∈ R, j ∈ Q) pairs, computed in device
+    blocks (``distances.oracle_pairs``) — the parity target of
+    ``query_batch``."""
+    return distances.oracle_pairs(index_data, delta, metric, q)

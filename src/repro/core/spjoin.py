@@ -110,6 +110,7 @@ class JoinResult:
     #   makespan_ratio)
     capacity_saved_bytes: int = 0  # modeled dispatch-buffer saving of the
     #   plan vs the contiguous global-max layout (cf. distributed executor)
+    map_fused: bool = False  # the map phase ran the single-pass map kernel
 
     @property
     def n_pairs(self) -> int:
@@ -354,6 +355,7 @@ def join(
         balance_std=float(dev_loads.std()),
         makespan_ratio=float(dev_loads.max(initial=0.0) / max(dev_loads.mean(), 1e-9)),
         capacity_saved_bytes=int(cap_saved),
+        map_fused=fused,
     )
 
 
@@ -480,17 +482,9 @@ def join_incremental(
 def brute_force_pairs(
     data: Array, delta: float, metric: str = "l1", s: Array | None = None
 ) -> np.ndarray:
-    """Ground-truth pair list for tests (quadratic; small inputs only).
+    """Ground-truth pair list for tests and the chip smoke run (quadratic
+    work, in device blocks — ``distances.oracle_pairs``).
 
     ``s=None``: self-join pairs (i, j), i < j. With ``s``: cross R×S pairs,
     column 0 indexing ``data`` (R), column 1 indexing ``s`` (S)."""
-    if s is None:
-        mask = np.asarray(distances.brute_force_join(jnp.asarray(data), delta, metric))
-    else:
-        mask = np.asarray(
-            distances.brute_force_join(
-                jnp.asarray(data), jnp.asarray(s), delta, metric
-            )
-        )
-    i, j = np.nonzero(mask)
-    return np.stack([i, j], axis=1).astype(np.int64)
+    return distances.oracle_pairs(data, delta, metric, s)

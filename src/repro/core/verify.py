@@ -196,6 +196,7 @@ class VerifyStats:
     n_overflow_retries: int = 0  # compact-emission re-dispatches (overflow sentinel)
     prune: str = "none"  # resolved prune mode the engine actually ran
     emit: str = "mask"  # resolved emission path the engine actually ran
+    backend: str = "numpy"  # resolved backend the tiles ran on
     bucket_shapes: set = dataclasses.field(default_factory=set)
 
     @property
@@ -786,7 +787,7 @@ def verify_cell_lists(
             np.asarray(coords, np.float32),
             float(delta_bound if delta_bound is not None else delta),
         )
-    stats = VerifyStats(prune=prune, emit=emit)
+    stats = VerifyStats(prune=prune, emit=emit, backend=backend)
     chunks: list[np.ndarray] = []
 
     for h, (v_idx, w_idx) in enumerate(zip(v_lists, w_lists)):

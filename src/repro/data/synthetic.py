@@ -82,6 +82,26 @@ def rs_mixture(
     return r, s
 
 
+def near_duplicates(n: int, m: int = 128, seed: int = 0) -> np.ndarray:
+    """Near-duplicate corpus with the SIFT descriptor shape: (n, m) float32
+    rows holding integers in [0, 255] (SIFT-1M stores uint8 features).
+
+    Rows fall into groups of 12 copies on average (Poisson sizes); each copy
+    is its group's base descriptor plus rounded N(0, 0.5) jitter, and the
+    bases scatter with σ = 12 around 256 cluster centres drawn uniformly
+    over the feature range. Integer features make every squared L2 distance
+    an exact integer in f32, so a threshold δ with δ² halfway between two
+    integers leaves no pair within rounding of the boundary."""
+    rng = np.random.default_rng(seed)
+    n_groups = max(1, round(n / 12))
+    centers = rng.uniform(0.0, 255.0, size=(256, m)).astype(np.float32)
+    bases = centers[rng.integers(0, 256, n_groups)]
+    bases += 12.0 * rng.standard_normal((n_groups, m), dtype=np.float32)
+    x = bases[rng.integers(0, n_groups, n)]
+    x += 0.5 * rng.standard_normal((n, m), dtype=np.float32)
+    return np.clip(np.rint(x), 0.0, 255.0, out=x)
+
+
 def heavy_tailed(n: int, m: int, alpha: float = 2.5, seed: int = 0) -> np.ndarray:
     """Pareto-tailed magnitudes (SIFT-like heavy local density variation)."""
     rng = np.random.default_rng(seed)

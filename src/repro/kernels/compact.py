@@ -1,48 +1,31 @@
 """Pallas TPU kernel: fused reduce phase with on-device pair compaction.
 
-The endgame form of the verify stage (ROADMAP "fully fused on-device reduce
-phase"): ONE pass per tile bucket does the pivot-filter pre-mask, the exact
+ONE jitted call per tile bucket does the pivot-filter pre-mask, the exact
 pairwise distance, the ``<= delta`` test, padding validity + the min-cell
-de-dup rule, and an exclusive prefix-sum compaction that scatters surviving
+de-dup rule, and a prefix-sum compaction that gathers surviving
 ``(v_id, w_id)`` pairs into a fixed-capacity output buffer. What leaves the
-kernel is output-sensitive — O(capacity) ids plus two counters — instead of
-the O(tile_v · tile_w) hit mask the host previously round-tripped through
+device is output-sensitive — O(capacity) ids plus two counters — instead of
+the O(tile_v · tile_w) hit mask the host would otherwise round-trip through
 ``np.asarray`` / ``np.nonzero`` per tile.
 
-Layout / pipelining (same scheme as ``pairdist.py``, whose accumulate /
-finalize helpers and pivot-bound loop this kernel shares):
+The work is split between a Pallas kernel and XLA, both on the device and
+inside the same jitted call:
 
-  * Grid (nv, nw, nm), feature chunks innermost: a VMEM f32 accumulator
-    carries partial distances across chunks, so the (a, b) distance matrix
-    never exists in HBM. Input tiles stream through Pallas' standard
-    double-buffered DMA pipeline — each V/W slab is touched once per grid
-    visit while compute overlaps the next tile's copy-in.
-  * The pair buffer and the counter row use CONSTANT index maps: Pallas
-    keeps them resident in VMEM across every grid step (the revisited-block
-    rule), so the compaction cursor survives the whole sweep and the buffer
-    is written back to HBM exactly once, at the end.
-  * Epilogue per (i, j) tile on the last feature chunk: finalize, threshold,
-    emit-mask (validity + min-cell de-dup, delegated semantics of
-    ``ref.emit_mask``), block-local exclusive ranks via row cumsum + row
-    offsets, then a value-level scatter ``buf.at[cursor + rank]`` with
-    ``mode="drop"`` — slots past ``capacity`` fall off, the cursor keeps the
-    TRUE total, and ``count > capacity`` is the overflow sentinel the engine
-    retries on.
-  * Prune variant: the L-inf pivot bound is computed once per (i, j) tile in
-    ``BP_CHUNK`` slices (exactly ``pairdist._filtered_kernel``'s loop) and a
-    whole-block ``pl.when`` skips the MXU/VPU accumulation when every pair
-    in the block is pruned — the on-accelerator analogue of the streaming
-    engine's tile skip.
+  * The Pallas kernel (grid (nv, nw, nm), feature chunks innermost, the
+    same accumulate / finalize helpers and pivot-bound loop as
+    ``pairdist.py``) writes one int8 code per pair: bit 0 = emitted hit
+    (threshold, validity, de-dup and, pruned, the bound), bit 1 = pivot-
+    filter candidate among valid pairs. The prune variant skips the MXU/VPU
+    accumulation of a block whose every pair the bound prunes — the
+    on-accelerator analogue of the streaming engine's tile skip.
+  * XLA then compacts bit 0 with ``ref.compact_mask`` (flat prefix sum +
+    searchsorted) and sums both bits into the counters. Mosaic has no
+    lowering for ``cumsum`` or for a value-level scatter, so the rank and
+    the gather cannot live in the kernel; the int8 code matrix is the one
+    O(tile) intermediate, and it stays in HBM.
 
-Emission ORDER is block-major (tiles in grid order, row-major within a
-tile), not global row-major: the engine sorts + uniques pairs at the end,
-and the parity suite order-normalizes, so order is a non-contract.
-
-Interpret-mode note: the scatter lowers through ``jnp``'s value-level
-``.at[]`` — exact in interpret mode (the CI path off-TPU) and on the Mosaic
-path it compiles to a serialized VMEM read-modify-write; block sizes keep
-the buffer well inside the ~16 MiB VMEM budget (capacity <= tile area is
-enforced by the engine's quarter-pow2 ladder).
+Emission order is therefore row-major, the same as ``ref.verify_compact``;
+a count above ``capacity`` is the overflow sentinel the engine retries on.
 
 Correctness contract (validated against ``ref.verify_compact`` in
 tests/test_reduce_fused.py): rows are zero-padded to block multiples by
@@ -59,114 +42,77 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pairdist import BP_CHUNK, MXU_METRICS, _accumulate, _finalize
+from repro.kernels import ref
+from repro.kernels.pairdist import (
+    CHUNK, _accumulate, _check_feature_block, _finalize, _pivot_bound,
+)
 
 
-def _compact_kernel(
+def _code_kernel(
     *refs,
     metric: str,
     delta: float,
     delta_bound: float | None,
     nm: int,
-    bp: int,
-    capacity: int,
     cross: bool,
     prune: bool,
 ):
-    """Fused verify + compaction. ``refs`` (prune variant adds px/py):
+    """Fused verify to a per-pair code. ``refs`` (prune variant adds px/py):
 
     inputs   x (bv, bm), y (bw, bm) [, px (bv, bp), py (bw, bp)],
-             vids (bv, 1) i32, wids (bw, 1) i32, wcells (bw, 1) i32,
-             cell_id (1, 1) i32
-    outputs  pairs (capacity, 2) i32   — constant index map, VMEM-resident
-             counts (1, 2) i32        — [total hits (cursor), candidates]
+             vids (bv, 1) i32, wids (1, bw) i32, wcells (1, bw) i32,
+             cell_id (1, 1) i32 in SMEM
+    output   code (bv, bw) int8 — bit 0 emitted hit, bit 1 candidate
     scratch  acc (bv, bw) f32 [, bound (bv, bw) f32]
     """
     if prune:
         (x_ref, y_ref, px_ref, py_ref, vids_ref, wids_ref, wcells_ref,
-         cell_ref, pairs_ref, counts_ref, acc_ref, bound_ref) = refs
+         cell_ref, code_ref, acc_ref, bound_ref) = refs
     else:
         (x_ref, y_ref, vids_ref, wids_ref, wcells_ref,
-         cell_ref, pairs_ref, counts_ref, acc_ref) = refs
+         cell_ref, code_ref, acc_ref) = refs
         bound_ref = None
-    iv, iw, im = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-
-    @pl.when((iv == 0) & (iw == 0) & (im == 0))
-    def _init_out():
-        # The outputs are revisited every grid step (constant index maps):
-        # initialize once, at the very first step.
-        pairs_ref[...] = jnp.full_like(pairs_ref, -1)
-        counts_ref[...] = jnp.zeros_like(counts_ref)
+    im = pl.program_id(2)
 
     @pl.when(im == 0)
     def _init_tile():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         if prune:
-            # Same bound loop as pairdist._filtered_kernel: the pivot axis is
-            # not grid-chunked (bp is small), BP_CHUNK slices keep the 3-d
-            # broadcast inside the VMEM budget.
-            pxc = px_ref[...].astype(jnp.float32)
-            pyc = py_ref[...].astype(jnp.float32)
-            bound = jnp.zeros_like(bound_ref)
-            for c in range(0, bp, BP_CHUNK):
-                bound = jnp.maximum(
-                    bound,
-                    jnp.abs(
-                        pxc[:, None, c : c + BP_CHUNK]
-                        - pyc[None, :, c : c + BP_CHUNK]
-                    ).max(-1),
-                )
-            bound_ref[...] = bound
+            bound_ref[...] = _pivot_bound(px_ref, py_ref)
+
+    def _live():
+        _accumulate(acc_ref, x_ref[...].astype(jnp.float32),
+                    y_ref[...].astype(jnp.float32), metric)
 
     if prune:
         # Whole-block skip: all pairs pruned -> the exact-distance hot loop
         # never runs for this feature chunk (acc stays zero; the epilogue's
         # bound conjunct forces an all-False mask regardless).
-        @pl.when((bound_ref[...] <= delta_bound).any())
-        def _live():
-            _accumulate(acc_ref, x_ref[...].astype(jnp.float32),
-                        y_ref[...].astype(jnp.float32), metric)
+        pl.when((bound_ref[...] <= delta_bound).any())(_live)
     else:
-        _accumulate(acc_ref, x_ref[...].astype(jnp.float32),
-                    y_ref[...].astype(jnp.float32), metric)
+        _live()
 
     @pl.when(im == nm - 1)
     def _epilogue():
-        vid = vids_ref[...][:, 0]  # (bv,)
-        wid = wids_ref[...][:, 0]  # (bw,)
-        valid = (vid[:, None] >= 0) & (wid[None, :] >= 0)
+        vid = vids_ref[...]  # (bv, 1)
+        wid = wids_ref[...]  # (1, bw)
+        valid = (vid >= 0) & (wid >= 0)
         hit = _finalize(acc_ref[...], metric) <= delta
         if prune:
-            cand = (bound_ref[...] <= delta_bound) & valid
-            hit = hit & (bound_ref[...] <= delta_bound)
+            live = bound_ref[...] <= delta_bound
+            cand = live & valid
+            hit = hit & live
         else:
             cand = valid
         if cross:
-            mask = hit & valid
+            emit = hit & valid
         else:
             # min-cell de-dup (ref.emit_mask semantics, inlined on refs).
-            wc = wcells_ref[...][:, 0]
+            wc = wcells_ref[...]
             cid = cell_ref[0, 0]
-            mask = hit & valid & (
-                (wc[None, :] > cid)
-                | ((wc[None, :] == cid) & (vid[:, None] < wid[None, :]))
-            )
-        # Block-local exclusive rank: row-major within the block via row
-        # cumsums + row offsets (a flat (bv*bw,) cumsum would defeat the VPU;
-        # two small cumsums don't).
-        m32 = mask.astype(jnp.int32)
-        prow = jnp.cumsum(m32, axis=1)
-        rowtot = prow[:, -1]
-        rank = prow - 1 + (jnp.cumsum(rowtot) - rowtot)[:, None]
-        cursor = counts_ref[0, 0]
-        slot = jnp.where(mask, cursor + rank, capacity).reshape(-1)
-        vv = jnp.broadcast_to(vid[:, None], mask.shape).reshape(-1)
-        wv = jnp.broadcast_to(wid[None, :], mask.shape).reshape(-1)
-        pairs_ref[...] = pairs_ref[...].at[slot].set(
-            jnp.stack([vv, wv], axis=1), mode="drop"
-        )
-        counts_ref[0, 0] = cursor + rowtot.sum()
-        counts_ref[0, 1] = counts_ref[0, 1] + cand.astype(jnp.int32).sum()
+            emit = hit & valid & ((wc > cid) | ((wc == cid) & (vid < wid)))
+        code = jnp.where(emit, 1, 0) + jnp.where(cand, 2, 0)
+        code_ref[...] = code.astype(code_ref.dtype)
 
 
 @functools.partial(
@@ -184,7 +130,7 @@ def verify_compact_blocked(
     wcells: jnp.ndarray,  # (b, 1) int32, padding = -1
     cell_id: jnp.ndarray,  # (1, 1) int32 — traced, NOT static (no recompiles
     #   per cell: the engine sweeps thousands of cells through one executable)
-    px: jnp.ndarray | None = None,  # (a, bp) mapped coords, bp % BP_CHUNK == 0
+    px: jnp.ndarray | None = None,  # (a, bp) mapped coords, bp % CHUNK == 0
     py: jnp.ndarray | None = None,  # (b, bp)
     *,
     metric: str,
@@ -201,21 +147,19 @@ def verify_compact_blocked(
     which handles padding, normalization and backend dispatch.
 
     Returns ``(pairs (capacity, 2) int32, counts (1, 2) int32)`` with
-    ``counts[0, 0]`` the TRUE hit total (> capacity == overflow; buffer
-    contents then unspecified) and ``counts[0, 1]`` the pivot-filter
-    candidate count (valid pair count when unpruned) — semantics of
-    ``ref.verify_compact`` up to emission order.
+    ``counts[0, 0]`` the TRUE hit total (> capacity == overflow; the buffer
+    then holds the first ``capacity`` hits) and ``counts[0, 1]`` the
+    pivot-filter candidate count (valid pair count when unpruned) —
+    semantics and row-major order of ``ref.verify_compact``.
     """
     a, m = x.shape
     b, _ = y.shape
     prune = px is not None
-    if bm is None:
-        bm = 128 if metric in MXU_METRICS else 16
-    bm = min(bm, m)
-    assert a % bv == 0 and b % bw == 0 and m % bm == 0, (x.shape, y.shape, bv, bw, bm)
+    bm = min(bm or 128, m)
+    assert a % bv == 0 and b % bw == 0, (x.shape, y.shape, bv, bw)
+    _check_feature_block(m, bm)
     assert vids.shape == (a, 1) and wids.shape == (b, 1) and wcells.shape == (b, 1)
     nm = m // bm
-    bp = 0
     inputs = [x, y]
     in_specs = [
         pl.BlockSpec((bv, bm), lambda i, j, k: (i, k)),
@@ -224,39 +168,38 @@ def verify_compact_blocked(
     if prune:
         assert py is not None
         bp = px.shape[1]
-        assert px.shape == (a, bp) and py.shape == (b, bp) and bp % BP_CHUNK == 0
+        assert px.shape == (a, bp) and py.shape == (b, bp) and bp % CHUNK == 0
         inputs += [px, py]
         in_specs += [
             pl.BlockSpec((bv, bp), lambda i, j, k: (i, 0)),
             pl.BlockSpec((bw, bp), lambda i, j, k: (j, 0)),
         ]
-    inputs += [vids, wids, wcells, cell_id.reshape(1, 1).astype(jnp.int32)]
+    # W ids ride as (1, b) rows so the epilogue broadcasts them along lanes.
+    inputs += [vids, wids.reshape(1, b), wcells.reshape(1, b),
+               cell_id.reshape(1, 1).astype(jnp.int32)]
     in_specs += [
         pl.BlockSpec((bv, 1), lambda i, j, k: (i, 0)),
-        pl.BlockSpec((bw, 1), lambda i, j, k: (j, 0)),
-        pl.BlockSpec((bw, 1), lambda i, j, k: (j, 0)),
-        pl.BlockSpec((1, 1), lambda i, j, k: (0, 0)),
+        pl.BlockSpec((1, bw), lambda i, j, k: (0, j)),
+        pl.BlockSpec((1, bw), lambda i, j, k: (0, j)),
+        pl.BlockSpec(memory_space=pltpu.SMEM),
     ]
     scratch = [pltpu.VMEM((bv, bw), jnp.float32)]
     if prune:
         scratch.append(pltpu.VMEM((bv, bw), jnp.float32))
-    pairs, counts = pl.pallas_call(
+    code = pl.pallas_call(
         functools.partial(
-            _compact_kernel, metric=metric, delta=float(delta),
+            _code_kernel, metric=metric, delta=float(delta),
             delta_bound=None if delta_bound is None else float(delta_bound),
-            nm=nm, bp=bp, capacity=capacity, cross=cross, prune=prune,
+            nm=nm, cross=cross, prune=prune,
         ),
         grid=(a // bv, b // bw, nm),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((capacity, 2), lambda i, j, k: (0, 0)),
-            pl.BlockSpec((1, 2), lambda i, j, k: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((capacity, 2), jnp.int32),
-            jax.ShapeDtypeStruct((1, 2), jnp.int32),
-        ],
+        out_specs=pl.BlockSpec((bv, bw), lambda i, j, k: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((a, b), jnp.int8),
         scratch_shapes=scratch,
         interpret=interpret,
     )(*inputs)
-    return pairs, counts
+    code = code.astype(jnp.int32)
+    pairs, count = ref.compact_mask((code & 1) == 1, vids[:, 0], wids[:, 0], capacity)
+    n_cand = (code >> 1).sum().astype(jnp.int32)
+    return pairs, jnp.stack([count, n_cand]).reshape(1, 2)

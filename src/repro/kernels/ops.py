@@ -78,6 +78,18 @@ def _pad_to(x: Array, mult: int, axis: int) -> Array:
     return jnp.pad(x, widths)
 
 
+def _feature_block(metric: str, m: int, bm: int | None) -> int:
+    """Feature (lane) block of a kernel call: ``bm`` (default 128), or for
+    the VPU metrics the whole width padded to the in-kernel chunk when that
+    fits one block — Mosaic accepts a last block dim that is a multiple of
+    128 or spans the array, and narrow l1/linf inputs then pad 16, not 128."""
+    bm = bm or 128
+    if metric in _pairdist.MXU_METRICS:
+        return bm
+    full = -(-m // _pairdist.CHUNK) * _pairdist.CHUNK
+    return full if full <= bm else bm
+
+
 def _prep(x: Array, y: Array, metric: str, bv: int, bw: int, bm: int):
     if metric not in METRICS:
         raise ValueError(f"unknown metric {metric!r}; kernels support {METRICS}")
@@ -108,11 +120,9 @@ def pairdist(
     """All-pairs distance matrix (a, b) float32."""
     if resolve_backend(backend, metric, use_kernel) == "numpy":
         return ref.pairdist(x, y, metric)
-    if bm is None:
-        bm = 128 if metric in _pairdist.MXU_METRICS else 16
+    bm = _feature_block(metric, x.shape[1], bm)
     a, b = x.shape[0], y.shape[0]
     xp, yp = _prep(x, y, metric, bv, bw, bm)
-    bm = min(bm, xp.shape[1])
     out = _pairdist.pairdist_blocked(
         xp, yp, metric=metric, delta=None, bv=bv, bw=bw, bm=bm, interpret=_interpret()
     )
@@ -138,11 +148,9 @@ def pairdist_mask(
     """Fused thresholded join mask (a, b) bool — distances never hit HBM."""
     if resolve_backend(backend, metric, use_kernel) == "numpy":
         return ref.pairdist_mask(x, y, delta, metric)
-    if bm is None:
-        bm = 128 if metric in _pairdist.MXU_METRICS else 16
+    bm = _feature_block(metric, x.shape[1], bm)
     a, b = x.shape[0], y.shape[0]
     xp, yp = _prep(x, y, metric, bv, bw, bm)
-    bm = min(bm, xp.shape[1])
     out = _pairdist.pairdist_blocked(
         xp,
         yp,
@@ -213,15 +221,13 @@ def pairdist_mask_filtered(
         delta_bound = ref.prune_delta(delta, metric)
     if resolve_backend(backend, metric, use_kernel) == "numpy":
         return ref.pairdist_mask_filtered(x, y, px, py, delta, metric, delta_bound)
-    if bm is None:
-        bm = 128 if metric in _pairdist.MXU_METRICS else 16
+    bm = _feature_block(metric, x.shape[1], bm)
     a, b = x.shape[0], y.shape[0]
     xp, yp = _prep(x, y, metric, bv, bw, bm)
-    bm = min(bm, xp.shape[1])
     # Pivot coords ride un-normalized (they are distances, not payload);
     # zero row/column padding is exact for the L-inf max.
-    pxp = _pad_to(_pad_to(px.astype(jnp.float32), bv, 0), _pairdist.BP_CHUNK, 1)
-    pyp = _pad_to(_pad_to(py.astype(jnp.float32), bw, 0), _pairdist.BP_CHUNK, 1)
+    pxp = _pad_to(_pad_to(px.astype(jnp.float32), bv, 0), _pairdist.CHUNK, 1)
+    pyp = _pad_to(_pad_to(py.astype(jnp.float32), bw, 0), _pairdist.CHUNK, 1)
     out = _pairdist.pairdist_filtered_blocked(
         xp, yp, pxp, pyp, metric=metric, delta=float(delta),
         delta_bound=float(delta_bound), bv=bv, bw=bw, bm=bm,
@@ -272,9 +278,8 @@ def verify_compact(
     pairs padded with -1, ``count`` int32 the TRUE hit total (``count >
     capacity`` == overflow -> the caller retries at the next capacity
     bucket), ``n_cand`` int32 the pivot-filter survivor count (== valid pair
-    count when unfiltered). Pair ORDER is backend-dependent (row-major on
-    numpy, block-major on Pallas) — callers sort/unique, parity tests
-    order-normalize. Semantics oracle: ``ref.verify_compact``.
+    count when unfiltered). Pairs come in row-major (``np.nonzero``) order
+    on both backends. Semantics oracle: ``ref.verify_compact``.
     """
     if capacity < 1:
         raise ValueError(f"capacity must be >= 1, got {capacity}")
@@ -300,10 +305,8 @@ def verify_compact(
             jnp.zeros((), jnp.int32),
             jnp.zeros((), jnp.int32),
         )
-    if bm is None:
-        bm = 128 if metric in _pairdist.MXU_METRICS else 16
+    bm = _feature_block(metric, x.shape[1], bm)
     xp, yp = _prep(x, y, metric, bv, bw, bm)
-    bm = min(bm, xp.shape[1])
     # Row padding carries id/wcell = -1 so padded rows fail the validity
     # mask — they can never be emitted or counted as candidates.
     vp = _pad_const(vids.astype(jnp.int32).reshape(-1, 1), bv, 0, -1)
@@ -313,8 +316,8 @@ def verify_compact(
     if px is not None:
         # Pivot coords ride un-normalized (they are distances, not payload);
         # zero row/column padding is exact for the L-inf max.
-        pxp = _pad_to(_pad_to(px.astype(jnp.float32), bv, 0), _pairdist.BP_CHUNK, 1)
-        pyp = _pad_to(_pad_to(py.astype(jnp.float32), bw, 0), _pairdist.BP_CHUNK, 1)
+        pxp = _pad_to(_pad_to(px.astype(jnp.float32), bv, 0), _pairdist.CHUNK, 1)
+        pyp = _pad_to(_pad_to(py.astype(jnp.float32), bw, 0), _pairdist.CHUNK, 1)
     pairs, counts = _compact.verify_compact_blocked(
         xp, yp, vp, wp, wcp, jnp.asarray(cell_id, jnp.int32).reshape(1, 1),
         pxp, pyp, metric=metric, delta=float(delta), capacity=capacity,
@@ -349,7 +352,7 @@ def histogram(
     weights: Array | None = None,
     *,
     bn: int = 256,
-    bmm: int = 8,
+    bmm: int = 128,
     backend: str = "auto",
     use_kernel: bool | None = None,
 ) -> Array:
@@ -470,10 +473,8 @@ def map_assign(
             jnp.zeros((0,), jnp.int32),
             jnp.zeros((0, words), jnp.uint32),
         )
-    if bm is None:
-        bm = 128 if metric in _pairdist.MXU_METRICS else 16
+    bm = _feature_block(metric, x.shape[1], bm)
     xp, ap = _prep(x, anchors, metric, bn, _ND_MULT, bm)
-    bm = min(bm, xp.shape[1])
     bpe = _bp_eff(p, bp)
     xm, cells, bits = _mapassign.map_assign_blocked(
         xp, ap, *_prep_boxes(kernel_lo, kernel_hi, whole_lo, whole_hi, bpe),

@@ -14,12 +14,15 @@ TPU adaptation of the paper's per-reducer verify loop (DESIGN.md par.2):
     distances).
   * MXU path (l2 / cosine / dot): the cross term is a (bv, bm) x (bm, bw)
     ``dot_general`` per chunk — systolic-array work, bm = 128 aligned.
-  * VPU path (l1 / linf): |x - y| reductions are elementwise; the chunk is
-    kept small (bm = 16) so the (bv, bw, bm) broadcast stays ~1 MiB in VMEM.
+  * VPU path (l1 / linf): |x - y| reductions are elementwise. The feature
+    block is lane-aligned like the MXU path (bm = 128, or the whole width
+    when it is narrower — Mosaic tiles a block's last dim by 128 unless it
+    spans the array), and the kernel walks it one feature at a time over
+    (bv, bw) tiles, so no 3-d intermediate exists.
   * Fused epilogue on the last chunk: sqrt / 1-minus, then optional
     ``<= delta`` mask in int8.
 
-Block sizes default to (128, 128, 128|16): MXU-aligned tiles; VMEM footprint
+Block sizes default to (128, 128, 128): MXU-aligned tiles; VMEM footprint
 per step = x(64 KiB) + y(64 KiB) + acc(64 KiB) + out tile, far under the
 ~16 MiB/core budget, leaving room for double-buffered pipelining.
 
@@ -41,11 +44,31 @@ MXU_METRICS = ("l2", "cosine", "dot")
 VPU_METRICS = ("l1", "linf")
 METRICS = MXU_METRICS + VPU_METRICS
 
+# Lane width of the in-kernel 3-d pivot-bound broadcast, and the multiple a
+# narrow l1/linf feature axis is padded to: (128, 128, CHUNK) f32 is ~1 MiB.
+CHUNK = 16
+
+
+def _check_feature_block(m: int, bm: int) -> None:
+    """Mosaic tiles a block's last dim by 128 unless it spans the array."""
+    assert m % bm == 0 and (bm == m or bm % 128 == 0), (m, bm)
+
 
 def _accumulate(acc_ref, xc, yc, metric: str) -> None:
-    """One feature chunk's contribution to the (bv, bw) distance accumulator
-    (shared by the plain and the filtered kernel)."""
-    if metric == "l2":
+    """One feature block's contribution to the (bv, bw) distance accumulator
+    (shared by the plain, filtered, compact and map kernels)."""
+    if metric in VPU_METRICS:
+        # One feature at a time on (bv, bw) tiles: an x column broadcast
+        # along lanes against the matching row of y transposed — 2-d VPU
+        # work only, no 3-d broadcast whose narrow lane dim Mosaic pads.
+        yt = yc.T  # (bm, bw)
+        acc = acc_ref[...]
+        for k in range(xc.shape[1]):
+            diff = jnp.abs(xc[:, k : k + 1] - yt[k : k + 1, :])
+            # max-accumulation: init 0 is correct because |.| >= 0.
+            acc = acc + diff if metric == "l1" else jnp.maximum(acc, diff)
+        acc_ref[...] = acc
+    elif metric == "l2":
         cross = jax.lax.dot_general(
             xc, yc, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
@@ -57,15 +80,22 @@ def _accumulate(acc_ref, xc, yc, metric: str) -> None:
         acc_ref[...] += jax.lax.dot_general(
             xc, yc, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
-    elif metric == "l1":
-        acc_ref[...] += jnp.abs(xc[:, None, :] - yc[None, :, :]).sum(-1)
-    elif metric == "linf":
-        # max-accumulation: init 0 is correct because |.| >= 0.
-        acc_ref[...] = jnp.maximum(
-            acc_ref[...], jnp.abs(xc[:, None, :] - yc[None, :, :]).max(-1)
-        )
     else:  # pragma: no cover — guarded by ops.py
         raise ValueError(metric)
+
+
+def _pivot_bound(px_ref, py_ref):
+    """(bv, bw) L-inf lower bound max_p |px_i[p] - py_j[p]| over the full
+    mapped coordinates, in ``CHUNK`` slices (the pivot axis is small and
+    never grid-chunked)."""
+    pxc = px_ref[...].astype(jnp.float32)
+    pyc = py_ref[...].astype(jnp.float32)
+    bound = jnp.zeros((pxc.shape[0], pyc.shape[0]), jnp.float32)
+    for c in range(0, pxc.shape[1], CHUNK):
+        bound = jnp.maximum(
+            bound, jnp.abs(pxc[:, None, c : c + CHUNK] - pyc[None, :, c : c + CHUNK]).max(-1)
+        )
+    return bound
 
 
 def _finalize(acc, metric: str):
@@ -123,10 +153,9 @@ def pairdist_blocked(
     handle padding, normalization and backend dispatch."""
     a, m = x.shape
     b, _ = y.shape
-    if bm is None:
-        bm = 128 if metric in MXU_METRICS else 16
-    bm = min(bm, m)
-    assert a % bv == 0 and b % bw == 0 and m % bm == 0, (x.shape, y.shape, bv, bw, bm)
+    bm = min(bm or 128, m)
+    assert a % bv == 0 and b % bw == 0, (x.shape, y.shape, bv, bw)
+    _check_feature_block(m, bm)
     nm = m // bm
     out_dtype = jnp.float32 if delta is None else jnp.int8
 
@@ -149,10 +178,6 @@ def pairdist_blocked(
 # Fused pivot-filter + pairdist (the verify engine's prune="pivot" hot path)
 # ---------------------------------------------------------------------------
 
-# Pivot-coordinate chunk for the bound broadcast: the (bv, bw, BP_CHUNK)
-# intermediate stays ~1 MiB in VMEM (same budget reasoning as the VPU bm=16).
-BP_CHUNK = 16
-
 
 def _filtered_kernel(
     x_ref,  # (bv, bm) VMEM — payload feature chunk
@@ -167,27 +192,14 @@ def _filtered_kernel(
     delta: float,
     delta_bound: float,
     nm: int,
-    bp: int,
 ):
     im = pl.program_id(2)
 
     @pl.when(im == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
-        # The pivot axis is NOT chunked by the grid (bp is small — n_dims
-        # padded); the bound is computed once per (i, j) tile, in BP_CHUNK
-        # slices so the 3-d broadcast stays within the VMEM budget.
-        pxc = px_ref[...].astype(jnp.float32)
-        pyc = py_ref[...].astype(jnp.float32)
-        bound = jnp.zeros_like(bound_ref)
-        for c in range(0, bp, BP_CHUNK):
-            bound = jnp.maximum(
-                bound,
-                jnp.abs(
-                    pxc[:, None, c : c + BP_CHUNK] - pyc[None, :, c : c + BP_CHUNK]
-                ).max(-1),
-            )
-        bound_ref[...] = bound
+        # Computed once per (i, j) tile.
+        bound_ref[...] = _pivot_bound(px_ref, py_ref)
 
     # Whole-tile skip: when the lower bound already exceeds delta for EVERY
     # pair in this (bv, bw) tile, the exact-distance accumulation (the MXU /
@@ -214,7 +226,7 @@ def _filtered_kernel(
 def pairdist_filtered_blocked(
     x: jnp.ndarray,  # (a, m) — a, m already padded to block multiples
     y: jnp.ndarray,  # (b, m)
-    px: jnp.ndarray,  # (a, bp) — mapped coords, bp padded to a BP_CHUNK multiple
+    px: jnp.ndarray,  # (a, bp) — mapped coords, bp padded to a CHUNK multiple
     py: jnp.ndarray,  # (b, bp)
     *,
     metric: str,
@@ -236,12 +248,11 @@ def pairdist_filtered_blocked(
     a, m = x.shape
     b, _ = y.shape
     bp = px.shape[1]
-    if bm is None:
-        bm = 128 if metric in MXU_METRICS else 16
-    bm = min(bm, m)
-    assert a % bv == 0 and b % bw == 0 and m % bm == 0, (x.shape, y.shape, bv, bw, bm)
-    assert px.shape == (a, bp) and py.shape == (b, bp) and bp % BP_CHUNK == 0, (
-        px.shape, py.shape, BP_CHUNK,
+    bm = min(bm or 128, m)
+    assert a % bv == 0 and b % bw == 0, (x.shape, y.shape, bv, bw)
+    _check_feature_block(m, bm)
+    assert px.shape == (a, bp) and py.shape == (b, bp) and bp % CHUNK == 0, (
+        px.shape, py.shape, CHUNK,
     )
     nm = m // bm
 
@@ -249,7 +260,7 @@ def pairdist_filtered_blocked(
     return pl.pallas_call(
         functools.partial(
             _filtered_kernel, metric=metric, delta=delta,
-            delta_bound=delta_bound, nm=nm, bp=bp,
+            delta_bound=delta_bound, nm=nm,
         ),
         grid=grid,
         in_specs=[

@@ -6,6 +6,7 @@ deliberately written in the most obvious form (no tiling, no fusion).
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 Array = jnp.ndarray
@@ -166,6 +167,32 @@ def emit_mask(
     )
 
 
+_SCAN_WIDTH = 128
+
+
+def prefix_sum(counts: Array, peak: int = 1) -> Array:
+    """Inclusive prefix sum of a flat int32 vector with entries in [0, peak].
+
+    ``jnp.cumsum`` of the same vector, computed in rows of 128 with one
+    upper-triangular matmul per level while that level's row sums (at most
+    128·peak) are integers f32 holds exactly (at most 2^24); the row totals
+    left then (n / 2^21 of them for a 0/1 mask) take a plain cumsum. The
+    TPU compiler takes tens of seconds over a plain cumsum of a
+    million-entry tile mask, and the engine compiles one per tile bucket;
+    this form compiles in a few seconds at any tile size."""
+    n = counts.shape[0]
+    if n <= _SCAN_WIDTH or peak * _SCAN_WIDTH > 1 << 24:
+        return jnp.cumsum(counts)
+    rows = jnp.pad(counts, (0, (-n) % _SCAN_WIDTH)).reshape(-1, _SCAN_WIDTH)
+    upper = jnp.triu(jnp.ones((_SCAN_WIDTH, _SCAN_WIDTH), jnp.float32))
+    inner = jnp.dot(
+        rows.astype(jnp.float32), upper, precision=jax.lax.Precision.HIGHEST
+    ).astype(jnp.int32)
+    row_total = inner[:, -1]
+    offset = prefix_sum(row_total, peak * _SCAN_WIDTH) - row_total
+    return (inner + offset[:, None]).reshape(-1)[:n]
+
+
 def compact_mask(
     mask: Array, vids: Array, wids: Array, capacity: int
 ) -> tuple[Array, Array]:
@@ -176,8 +203,7 @@ def compact_mask(
     (``np.nonzero``) order, padded with -1; ``count`` is int32 and equals the
     TRUE total number of hits — ``count > capacity`` signals overflow, in
     which case the retained prefix is the first ``capacity`` hits but callers
-    must treat the buffer as unspecified and retry at a larger capacity (the
-    Pallas kernel fills it in block-major, not row-major, order).
+    must treat the buffer as unspecified and retry at a larger capacity.
 
     Scatter-free formulation (the jnp/XLA fast path): the k-th hit's flat
     position is the first index where the inclusive prefix sum of the
@@ -190,7 +216,7 @@ def compact_mask(
             jnp.full((capacity, 2), -1, jnp.int32),
             jnp.zeros((), jnp.int32),
         )
-    incl = jnp.cumsum(mask.astype(jnp.int32).reshape(-1))
+    incl = prefix_sum(mask.astype(jnp.int32).reshape(-1))
     count = incl[-1].astype(jnp.int32)
     q = jnp.arange(1, capacity + 1, dtype=incl.dtype)
     pos = jnp.minimum(jnp.searchsorted(incl, q, side="left"), a * b - 1)

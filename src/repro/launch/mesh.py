@@ -22,10 +22,27 @@ roofline denominators ``benchmarks/roofline.py`` renders.
 from __future__ import annotations
 
 import dataclasses
+import os
+import pathlib
 
 import jax
 import numpy as np
 from jax.sharding import Mesh
+
+# Fixed, so that every process of a checkout finds what earlier ones cached.
+COMPILE_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point and
+    return its directory. Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX
+    reads it itself and nothing is changed; otherwise the cache lives in
+    ``<repo root>/.jax_cache``. Call it from ``main``, never at import."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+    return str(COMPILE_CACHE_DIR)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

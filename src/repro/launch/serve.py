@@ -33,6 +33,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.mesh import use_compile_cache
+
 
 # ---------------------------------------------------------------------------
 # range: metric-index query serving (build once, query millions)
@@ -179,6 +181,7 @@ def main() -> None:
     lp.set_defaults(fn=serve_lm)
 
     args = ap.parse_args(argv)
+    use_compile_cache()
     args.fn(args)
 
 

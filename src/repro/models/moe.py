@@ -28,7 +28,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.models import layers
 from repro.models.base import current_act_rules, current_mesh, pdef, shard_act
 
@@ -223,7 +222,7 @@ def moe_block(params: dict, x: Array, cfg, group_size: int = 2048):
                 "down": P("model", None, None),
             },
         )
-        y, aux = compat.shard_map(
+        y, aux = jax.shard_map(
             body, mesh=mesh, in_specs=in_specs,
             out_specs=(P(bspec, None, None), P()),
             check_vma=False,

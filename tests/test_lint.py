@@ -333,7 +333,7 @@ class TestJaxprAudit:
         def promoting(x):
             return x.astype(jnp.float64)
 
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             entry = jaxpr_audit.trace_entry(
                 "bad_f64", promoting, (jnp.zeros((4,), jnp.float32),)
             )
@@ -346,7 +346,6 @@ class TestJaxprAudit:
         import jax.numpy as jnp
         import numpy as np
         from jax.sharding import Mesh
-        from repro.compat import shard_map
 
         mesh = Mesh(np.array(jax.devices("cpu")[:1]), ("data",))
 
@@ -355,7 +354,7 @@ class TestJaxprAudit:
             b = jax.lax.all_to_all(x[None], "data", 0, 0)
             return a + b
 
-        fn = shard_map(
+        fn = jax.shard_map(
             noisy, mesh=mesh, in_specs=jax.sharding.PartitionSpec("data"),
             out_specs=jax.sharding.PartitionSpec("data"),
         )
@@ -401,7 +400,7 @@ class TestJaxprAudit:
         def outer(x):
             return inner(x) + 1
 
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             entry = jaxpr_audit.trace_entry(
                 "nested", outer, (jnp.zeros((4,), jnp.float32),)
             )

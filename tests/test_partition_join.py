@@ -98,11 +98,17 @@ def test_mapping_is_lipschitz(rng):
 )
 def test_join_equals_brute_force(seed, metric, sampler, partitioner, delta_q):
     """THE paper invariant: SP-Join output == brute-force join, for any
-    sampler/partitioner/metric/threshold."""
+    sampler/partitioner/metric/threshold.
+
+    Rows sit on a 1/16 grid, so every distance (and L2's squared distance)
+    is exact in f32 under both the kernels' dot expansion and the oracle's
+    Σ(x−y)²: a quantile threshold can tie a pair's distance, but never sit
+    within rounding of it, where the two formulas may round apart."""
     rng = np.random.default_rng(seed)
     data = np.concatenate([
         rng.normal(loc=c, scale=1.0, size=(120, 5)) for c in (0.0, 4.0, 9.0)
-    ]).astype(np.float32)
+    ])
+    data = (np.rint(data * 16) / 16).astype(np.float32)
     d = np.asarray(distances.pairwise(jnp.asarray(data), jnp.asarray(data), metric))
     delta = float(np.quantile(d[np.triu_indices(len(data), 1)], delta_q))
     cfg = spjoin.JoinConfig(
@@ -120,6 +126,27 @@ def test_join_on_minhash_metric(rng):
     res = spjoin.join(sigs, cfg)
     truth = spjoin.brute_force_pairs(sigs, 0.5, "jaccard_minhash")
     assert np.array_equal(res.pairs, truth)
+
+
+@pytest.mark.parametrize("cross", [False, True])
+@pytest.mark.parametrize("metric", ["l1", "l2", "linf", "jaccard_minhash"])
+def test_blocked_oracle_matches_dense(rng, metric, cross):
+    """The blocked device oracle equals the dense matrix oracle with blocks
+    smaller than n: several row and column blocks, the self-join diagonal,
+    and the overflow retry (far more hits per block than the first
+    capacity). Integer data keeps both L2 forms exact."""
+    x = rng.integers(0, 5, size=(203, 6)).astype(np.float32)
+    y = rng.integers(0, 5, size=(77, 6)).astype(np.float32) if cross else None
+    d = np.asarray(distances.pairwise(jnp.asarray(x), jnp.asarray(x), metric))
+    delta = float(np.quantile(d, 0.2))
+    dense = np.asarray(
+        distances.brute_force_join(x, y, delta, metric)
+        if cross
+        else distances.brute_force_join(x, delta, metric)
+    )
+    got = distances.oracle_pairs(x, delta, metric, y, block=32)
+    assert got.shape[0] > 32
+    np.testing.assert_array_equal(got, np.argwhere(dense))
 
 
 def test_tighten_preserves_exactness(rng):

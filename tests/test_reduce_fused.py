@@ -108,6 +108,15 @@ def test_overflow_sentinel_reports_exact_count(seed, capacity):
     assert int(cnt) == count
 
 
+@pytest.mark.parametrize("n,peak", [(40_000, 1), (2_000, 1 << 17), (2_000, 1 << 20)])
+def test_prefix_sum_matches_cumsum(n, peak):
+    """The matmul prefix sum equals ``np.cumsum`` for entries up to ``peak``,
+    including where a level's row sums would pass 2^24 in f32."""
+    counts = np.random.default_rng(peak).integers(0, peak + 1, n).astype(np.int32)
+    got = np.asarray(kref.prefix_sum(jnp.asarray(counts), peak))
+    assert np.array_equal(got, np.cumsum(counts, dtype=np.int32))
+
+
 def test_compaction_edge_tiles():
     """Edge tiles: empty, all-pruned, exactly-full, and a single hit at flat
     index 0 / at the last flat cell landing in buffer slot 0 / capacity-1."""

@@ -5,7 +5,7 @@ jitted function (which rots on the first refactor), the linter finds traced
 scopes STRUCTURALLY:
 
   * decorated: ``@jax.jit`` / ``@functools.partial(jax.jit, ...)``
-  * passed to a tracer: ``jax.jit(f, ...)``, ``compat.shard_map(f, ...)``,
+  * passed to a tracer: ``jax.jit(f, ...)``, ``jax.shard_map(f, ...)``,
     ``jax.vmap(f)``, ``jax.lax.scan(f, ...)``, ``jax.lax.switch(i, [f, g])``,
     ``pl.pallas_call(f, ...)`` — including module-level aliases like
     ``_tile_verify = jax.jit(verify_tile, static_argnames=...)``

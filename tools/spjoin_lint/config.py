@@ -19,7 +19,7 @@ Two-tier hot-scope model (docs/INVARIANTS.md):
             bodies, where they must carry a waiver with a justification.
 
 Traced scopes are mostly DETECTED structurally (functions passed to
-``jax.jit`` / ``compat.shard_map`` / ``jax.vmap`` / ``jax.lax.scan`` /
+``jax.jit`` / ``jax.shard_map`` / ``jax.vmap`` / ``jax.lax.scan`` /
 ``pl.pallas_call``, plus everything they call in the same module); the
 lists below only add what structure cannot see (closures returned by a
 factory and invoked through a variable) and the stream tier, which is a
