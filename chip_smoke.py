@@ -11,11 +11,13 @@ rounding of the threshold. Phases, each through the entry points a user
 calls, cold (compile included) and warm:
 
   join         ``spjoin.join`` with the default backend
-  distributed  ``distributed_join`` on ``make_host_mesh()``, first 2^18 rows
+  join-compact ``spjoin.join`` with ``emit="compact"`` (``ops.verify_compact``:
+               Pallas distances, compaction on the device), first 2^19 rows
+  distributed  ``distributed_join`` on ``make_host_mesh()``, first 2^19 rows
   serve        ``build_index`` → ``to_distributed(make_host_mesh())`` →
                ``query_batch`` of 4,096 held-out queries, several times
 
-``--four-chips`` runs only ``distributed_join`` over the same 2^18 rows on
+``--four-chips`` runs only ``distributed_join`` over the same 2^19 rows on
 a 4-chip mesh, and prints the rows each device holds after the shuffle.
 
 Every phase's pairs must be byte-identical to the blocked device oracle
@@ -27,6 +29,7 @@ one JSON line last. It refuses to run where JAX finds no TPU.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import os
 import sys
@@ -37,11 +40,14 @@ import jax.numpy as jnp
 import numpy as np
 
 ROWS = 1 << 20
-# The one-shot distributed executor holds every slot's W rows padded to the
-# largest slot, plus one slot's padded V×W verification area: compiled for a
-# v5e at 2^20 of these rows with 256 cells it needs 18.25 GB of the chip's
-# 15.75 GB, so its phases join the first 2^18 rows.
-DIST_ROWS = 1 << 18
+# The one-shot distributed executor verifies each slot as one dense padded
+# V×W tile on one device. At 2^20 of these rows with 64 cells the largest
+# slot is 39,752 × 107,768 on one chip and 40,588 × 108,468 on four: more
+# pairs than the int32 flat index of its compaction can address, so the
+# verify stage does not trace on either mesh. At 2^19 it compiles for a
+# v5e in 11.80 GB of temporaries (one chip) and 9.84 GB per chip (four), of
+# 15.75 GB. Its phases therefore join the first 2^19 rows.
+DIST_ROWS = 1 << 19
 NEIGHBOURS = 10.0
 PARTITIONS = 64  # kernel cells p, in every phase
 QUERY_BATCH = 4096
@@ -111,21 +117,23 @@ def _report(phase: str, **fields) -> None:
     print(f"[{phase}] {body} peak_bytes_in_use={_peak_bytes()}", flush=True)
 
 
-def phase_join(data, truth, cfg) -> None:
+def phase_join(data, truth, cfg, phase="join") -> None:
     from repro.core import spjoin
 
     res, cold = _timed(lambda: spjoin.join(data, cfg))
     res, warm = _timed(lambda: spjoin.join(data, cfg))
     vs = res.verify_stats
     _report(
-        "join", rows=data.shape[0], cold_s=cold, warm_s=warm, pairs=res.n_pairs,
-        backend=vs.backend, map_fused=res.map_fused, sample_s=res.sample_time_s,
-        map_s=res.map_time_s, verify_s=res.verify_time_s, tiles=vs.n_tiles,
-        tiles_pruned=vs.n_tiles_pruned, prune_rate=vs.prune_rate,
+        phase, rows=data.shape[0], cold_s=cold, warm_s=warm, pairs=res.n_pairs,
+        backend=vs.backend, emit=vs.emit, map_fused=res.map_fused,
+        sample_s=res.sample_time_s, map_s=res.map_time_s, verify_s=res.verify_time_s,
+        tiles=vs.n_tiles, tiles_pruned=vs.n_tiles_pruned, prune_rate=vs.prune_rate,
+        overflow_retries=vs.n_overflow_retries,
     )
-    _require("join", f"verify ran on {vs.backend!r}, not pallas", vs.backend == "pallas")
-    _require("join", "map phase did not run the fused kernel", res.map_fused)
-    _check_pairs("join", res.pairs, truth)
+    _require(phase, f"verify ran on {vs.backend!r}, not pallas", vs.backend == "pallas")
+    _require(phase, f"verify emitted by {vs.emit!r}, not {cfg.emit!r}", vs.emit == cfg.emit)
+    _require(phase, "map phase did not run the fused kernel", res.map_fused)
+    _check_pairs(phase, res.pairs, truth)
 
 
 def phase_distributed(data, delta, truth, mesh, phase="distributed") -> None:
@@ -209,8 +217,8 @@ def main() -> int:
           f"metric=l2 delta={delta!r} mean_neighbours_on_sample={neighbours}", flush=True)
     # The distributed phases join the first DIST_ROWS rows under the same δ.
     print(f"cut: distributed_join runs on the first {DIST_ROWS} of {ROWS} rows, "
-          "on one chip and on four (at full size its dispatch buffers outgrow "
-          "one chip's HBM)", flush=True)
+          "on one chip and on four (at full size its largest slot holds more "
+          "V×W pairs than its int32 compaction index addresses)", flush=True)
     rows = DIST_ROWS if args.four_chips else ROWS
     truth, oracle_s = _timed(lambda: spjoin.brute_force_pairs(data[:rows], delta, "l2"))
     print(f"oracle rows={rows} pairs={truth.shape[0]} time_s={oracle_s}", flush=True)
@@ -224,6 +232,9 @@ def main() -> int:
         cfg = spjoin.JoinConfig(delta=delta, metric="l2", p=PARTITIONS)
         mesh = mesh_lib.make_host_mesh()
         phase_join(data, truth, cfg)
+        # The compact-emission path (ops.verify_compact), on the distributed rows.
+        phase_join(data[:DIST_ROWS], dist_truth, dataclasses.replace(cfg, emit="compact"),
+                   phase="join-compact")
         phase_distributed(data[:DIST_ROWS], delta, dist_truth, mesh)
         phase_serve(data, queries, delta, cfg, mesh)
 

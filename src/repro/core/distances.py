@@ -32,8 +32,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import ref as kref
-
 Array = jnp.ndarray
 
 
@@ -171,6 +169,35 @@ def _oracle_distance(x: Array, y: Array, metric: str) -> Array:
     return pairwise(x, y, metric)
 
 
+def _first_hits(hit: Array, rows: Array, cols: Array, cap: int) -> tuple[Array, Array]:
+    """The first ``cap`` hits of a bool block in row-major order as (cap, 2)
+    (row, col) ids padded with -1, and the true hit count. The slot of the
+    k-th hit is found by row (a search over the row-count prefix sum), then
+    by 128-column chunk within that row, then by column within the chunk,
+    each the first place where a running count passes the rank — a path
+    that shares no code with the engine's compaction, and whose prefix
+    sums are all short."""
+
+    def first_past(counts, rank):  # (cap, k) counts: index, and rank left there
+        end = jnp.cumsum(counts, axis=1)
+        i = jnp.argmax(end > rank[:, None], axis=1)
+        before = jnp.take_along_axis(end - counts, i[:, None], axis=1)[:, 0]
+        return i, rank - before
+
+    b = hit.shape[1]
+    chunk = 128 if b % 128 == 0 else b
+    row_cnt = hit.sum(1, dtype=jnp.int32)
+    row_end = jnp.cumsum(row_cnt)
+    q = jnp.arange(cap, dtype=jnp.int32)
+    r = jnp.minimum(jnp.searchsorted(row_end, q, side="right"), hit.shape[0] - 1)
+    chunks = hit[r].reshape(cap, b // chunk, chunk)
+    c, rank = first_past(chunks.sum(2, dtype=jnp.int32), q - (row_end[r] - row_cnt[r]))
+    inner = jnp.take_along_axis(chunks, c[:, None, None], axis=1)[:, 0]
+    j, _ = first_past(inner.astype(jnp.int32), rank)
+    pairs = jnp.stack([rows[r], cols[c * chunk + j]], 1).astype(jnp.int32)
+    return jnp.where((q < row_end[-1])[:, None], pairs, -1), row_end[-1]
+
+
 @functools.partial(jax.jit, static_argnames=("metric", "block", "cap", "self_join"))
 def _oracle_row_block(x, y, r0, n_x, n_y, delta, *, metric, block, cap, self_join):
     """Hits of rows [r0, r0 + block) of ``x`` against every column block of
@@ -188,7 +215,7 @@ def _oracle_row_block(x, y, r0, n_x, n_y, delta, *, metric, block, cap, self_joi
             )
             if self_join:
                 hit = hit & (cols[None, :] > rows[:, None])
-            return kref.compact_mask(hit, rows, cols, cap)
+            return _first_hits(hit, rows, cols, cap)
 
         if not self_join:
             return hits(None)
@@ -230,7 +257,7 @@ def oracle_pairs(
 
     x_dev = put(x_np)
     y_dev = x_dev if self_join else put(y_np)
-    cap = max(block // 4, 8)
+    cap = max(block // 16, 8)
     out = []
     for r0 in range(0, n_x, block):
         while True:

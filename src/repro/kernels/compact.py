@@ -1,4 +1,4 @@
-"""Pallas TPU kernel: fused reduce phase with on-device pair compaction.
+"""Fused reduce phase with on-device pair compaction.
 
 ONE jitted call per tile bucket does the pivot-filter pre-mask, the exact
 pairwise distance, the ``<= delta`` test, padding validity + the min-cell
@@ -11,18 +11,18 @@ the O(tile_v · tile_w) hit mask the host would otherwise round-trip through
 The work is split between a Pallas kernel and XLA, both on the device and
 inside the same jitted call:
 
-  * The Pallas kernel (grid (nv, nw, nm), feature chunks innermost, the
-    same accumulate / finalize helpers and pivot-bound loop as
-    ``pairdist.py``) writes one int8 code per pair: bit 0 = emitted hit
-    (threshold, validity, de-dup and, pruned, the bound), bit 1 = pivot-
-    filter candidate among valid pairs. The prune variant skips the MXU/VPU
-    accumulation of a block whose every pair the bound prunes — the
-    on-accelerator analogue of the streaming engine's tile skip.
-  * XLA then compacts bit 0 with ``ref.compact_mask`` (flat prefix sum +
-    searchsorted) and sums both bits into the counters. Mosaic has no
-    lowering for ``cumsum`` or for a value-level scatter, so the rank and
-    the gather cannot live in the kernel; the int8 code matrix is the one
-    O(tile) intermediate, and it stays in HBM.
+  * The distance kernels of ``pairdist.py`` write one int8 per pair. Pruned,
+    ``pairdist_filtered_blocked(candidates=True)`` sets bit 0 for a hit that
+    survives the pivot bound and bit 1 for a pair the bound keeps, and skips
+    the MXU/VPU accumulation of a block whose every pair the bound prunes —
+    the on-accelerator analogue of the streaming engine's tile skip.
+    Unpruned, ``pairdist_blocked`` writes the hit mask.
+  * XLA then applies validity and the min-cell de-dup (``ref.emit_mask``),
+    compacts the hits with ``ref.compact_mask`` (flat prefix sum +
+    searchsorted) and counts the candidates. Mosaic has no lowering for
+    ``cumsum`` or for a value-level scatter, so the rank and the gather
+    cannot live in the kernel; the int8 matrix is the one O(tile)
+    intermediate, and it stays in HBM.
 
 Emission order is therefore row-major, the same as ``ref.verify_compact``;
 a count above ``capacity`` is the overflow sentinel the engine retries on.
@@ -39,80 +39,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import ref
-from repro.kernels.pairdist import (
-    CHUNK, _accumulate, _check_feature_block, _finalize, _pivot_bound,
-)
-
-
-def _code_kernel(
-    *refs,
-    metric: str,
-    delta: float,
-    delta_bound: float | None,
-    nm: int,
-    cross: bool,
-    prune: bool,
-):
-    """Fused verify to a per-pair code. ``refs`` (prune variant adds px/py):
-
-    inputs   x (bv, bm), y (bw, bm) [, px (bv, bp), py (bw, bp)],
-             vids (bv, 1) i32, wids (1, bw) i32, wcells (1, bw) i32,
-             cell_id (1, 1) i32 in SMEM
-    output   code (bv, bw) int8 — bit 0 emitted hit, bit 1 candidate
-    scratch  acc (bv, bw) f32 [, bound (bv, bw) f32]
-    """
-    if prune:
-        (x_ref, y_ref, px_ref, py_ref, vids_ref, wids_ref, wcells_ref,
-         cell_ref, code_ref, acc_ref, bound_ref) = refs
-    else:
-        (x_ref, y_ref, vids_ref, wids_ref, wcells_ref,
-         cell_ref, code_ref, acc_ref) = refs
-        bound_ref = None
-    im = pl.program_id(2)
-
-    @pl.when(im == 0)
-    def _init_tile():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        if prune:
-            bound_ref[...] = _pivot_bound(px_ref, py_ref)
-
-    def _live():
-        _accumulate(acc_ref, x_ref[...].astype(jnp.float32),
-                    y_ref[...].astype(jnp.float32), metric)
-
-    if prune:
-        # Whole-block skip: all pairs pruned -> the exact-distance hot loop
-        # never runs for this feature chunk (acc stays zero; the epilogue's
-        # bound conjunct forces an all-False mask regardless).
-        pl.when((bound_ref[...] <= delta_bound).any())(_live)
-    else:
-        _live()
-
-    @pl.when(im == nm - 1)
-    def _epilogue():
-        vid = vids_ref[...]  # (bv, 1)
-        wid = wids_ref[...]  # (1, bw)
-        valid = (vid >= 0) & (wid >= 0)
-        hit = _finalize(acc_ref[...], metric) <= delta
-        if prune:
-            live = bound_ref[...] <= delta_bound
-            cand = live & valid
-            hit = hit & live
-        else:
-            cand = valid
-        if cross:
-            emit = hit & valid
-        else:
-            # min-cell de-dup (ref.emit_mask semantics, inlined on refs).
-            wc = wcells_ref[...]
-            cid = cell_ref[0, 0]
-            emit = hit & valid & ((wc > cid) | ((wc == cid) & (vid < wid)))
-        code = jnp.where(emit, 1, 0) + jnp.where(cand, 2, 0)
-        code_ref[...] = code.astype(code_ref.dtype)
+from repro.kernels.pairdist import pairdist_blocked, pairdist_filtered_blocked
 
 
 @functools.partial(
@@ -152,54 +81,21 @@ def verify_compact_blocked(
     pivot-filter candidate count (valid pair count when unpruned) —
     semantics and row-major order of ``ref.verify_compact``.
     """
-    a, m = x.shape
-    b, _ = y.shape
-    prune = px is not None
-    bm = min(bm or 128, m)
-    assert a % bv == 0 and b % bw == 0, (x.shape, y.shape, bv, bw)
-    _check_feature_block(m, bm)
+    a, b = x.shape[0], y.shape[0]
     assert vids.shape == (a, 1) and wids.shape == (b, 1) and wcells.shape == (b, 1)
-    nm = m // bm
-    inputs = [x, y]
-    in_specs = [
-        pl.BlockSpec((bv, bm), lambda i, j, k: (i, k)),
-        pl.BlockSpec((bw, bm), lambda i, j, k: (j, k)),
-    ]
-    if prune:
-        assert py is not None
-        bp = px.shape[1]
-        assert px.shape == (a, bp) and py.shape == (b, bp) and bp % CHUNK == 0
-        inputs += [px, py]
-        in_specs += [
-            pl.BlockSpec((bv, bp), lambda i, j, k: (i, 0)),
-            pl.BlockSpec((bw, bp), lambda i, j, k: (j, 0)),
-        ]
-    # W ids ride as (1, b) rows so the epilogue broadcasts them along lanes.
-    inputs += [vids, wids.reshape(1, b), wcells.reshape(1, b),
-               cell_id.reshape(1, 1).astype(jnp.int32)]
-    in_specs += [
-        pl.BlockSpec((bv, 1), lambda i, j, k: (i, 0)),
-        pl.BlockSpec((1, bw), lambda i, j, k: (0, j)),
-        pl.BlockSpec((1, bw), lambda i, j, k: (0, j)),
-        pl.BlockSpec(memory_space=pltpu.SMEM),
-    ]
-    scratch = [pltpu.VMEM((bv, bw), jnp.float32)]
-    if prune:
-        scratch.append(pltpu.VMEM((bv, bw), jnp.float32))
-    code = pl.pallas_call(
-        functools.partial(
-            _code_kernel, metric=metric, delta=float(delta),
-            delta_bound=None if delta_bound is None else float(delta_bound),
-            nm=nm, cross=cross, prune=prune,
-        ),
-        grid=(a // bv, b // bw, nm),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((bv, bw), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((a, b), jnp.int8),
-        scratch_shapes=scratch,
-        interpret=interpret,
-    )(*inputs)
-    code = code.astype(jnp.int32)
-    pairs, count = ref.compact_mask((code & 1) == 1, vids[:, 0], wids[:, 0], capacity)
-    n_cand = (code >> 1).sum().astype(jnp.int32)
-    return pairs, jnp.stack([count, n_cand]).reshape(1, 2)
+    blocks = dict(bv=bv, bw=bw, bm=bm, interpret=interpret)
+    vid, wid = vids[:, 0], wids[:, 0]
+    valid = (vid[:, None] >= 0) & (wid[None, :] >= 0)
+    if px is not None:
+        code = pairdist_filtered_blocked(
+            x, y, px, py, metric=metric, delta=float(delta),
+            delta_bound=float(delta_bound), candidates=True, **blocks,
+        ).astype(jnp.int32)
+        hit = (code & 1) == 1
+        n_cand = (((code >> 1) == 1) & valid).sum()
+    else:
+        hit = pairdist_blocked(x, y, metric=metric, delta=float(delta), **blocks) == 1
+        n_cand = valid.sum()
+    hit = hit & ref.emit_mask(vid, wid, wcells[:, 0], cell_id[0, 0], cross)
+    pairs, count = ref.compact_mask(hit, vid, wid, capacity)
+    return pairs, jnp.stack([count, n_cand.astype(jnp.int32)]).reshape(1, 2)

@@ -184,7 +184,7 @@ def _filtered_kernel(
     y_ref,  # (bw, bm) VMEM
     px_ref,  # (bv, bp) VMEM — FULL mapped coordinates (anchor distances)
     py_ref,  # (bw, bp) VMEM
-    out_ref,  # (bv, bw) int8 mask
+    out_ref,  # (bv, bw) int8 mask, or code with ``candidates``
     acc_ref,  # (bv, bw) f32 scratch — distance accumulator
     bound_ref,  # (bv, bw) f32 scratch — L-inf pivot lower bound
     *,
@@ -192,6 +192,7 @@ def _filtered_kernel(
     delta: float,
     delta_bound: float,
     nm: int,
+    candidates: bool,
 ):
     im = pl.program_id(2)
 
@@ -213,15 +214,19 @@ def _filtered_kernel(
 
     @pl.when(im == nm - 1)
     def _epilogue():
-        acc = _finalize(acc_ref[...], metric)
-        out_ref[...] = ((acc <= delta) & (bound_ref[...] <= delta_bound)).astype(
-            out_ref.dtype
-        )
+        live = bound_ref[...] <= delta_bound
+        hit = (_finalize(acc_ref[...], metric) <= delta) & live
+        if candidates:
+            out_ref[...] = (jnp.where(hit, 1, 0) + jnp.where(live, 2, 0)).astype(out_ref.dtype)
+        else:
+            out_ref[...] = hit.astype(out_ref.dtype)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("metric", "delta", "delta_bound", "bv", "bw", "bm", "interpret"),
+    static_argnames=(
+        "metric", "delta", "delta_bound", "candidates", "bv", "bw", "bm", "interpret",
+    ),
 )
 def pairdist_filtered_blocked(
     x: jnp.ndarray,  # (a, m) — a, m already padded to block multiples
@@ -232,6 +237,7 @@ def pairdist_filtered_blocked(
     metric: str,
     delta: float,
     delta_bound: float,
+    candidates: bool = False,
     bv: int = 128,
     bw: int = 128,
     bm: int | None = None,
@@ -242,8 +248,10 @@ def pairdist_filtered_blocked(
 
     Semantics (validated against ``ref.pairdist_mask_filtered``): int8 mask,
     1 where D(x_i, y_j) <= delta AND max_p |px_i[p] - py_j[p]| <= delta_bound.
-    Zero padding is exact on both the feature and the pivot axis (|0-0| = 0
-    contributes nothing to sum or max).
+    ``candidates=True`` adds bit 1 where the bound alone holds (the pivot-
+    filter survivors ``ops.verify_compact`` counts). Zero padding is exact on
+    both the feature and the pivot axis (|0-0| = 0 contributes nothing to
+    sum or max).
     """
     a, m = x.shape
     b, _ = y.shape
@@ -260,7 +268,7 @@ def pairdist_filtered_blocked(
     return pl.pallas_call(
         functools.partial(
             _filtered_kernel, metric=metric, delta=delta,
-            delta_bound=delta_bound, nm=nm,
+            delta_bound=delta_bound, nm=nm, candidates=candidates,
         ),
         grid=grid,
         in_specs=[

@@ -100,17 +100,27 @@ def test_join_equals_brute_force(seed, metric, sampler, partitioner, delta_q):
     """THE paper invariant: SP-Join output == brute-force join, for any
     sampler/partitioner/metric/threshold.
 
-    Rows sit on a 1/16 grid, so every distance (and L2's squared distance)
-    is exact in f32 under both the kernels' dot expansion and the oracle's
-    Σ(x−y)²: a quantile threshold can tie a pair's distance, but never sit
-    within rounding of it, where the two formulas may round apart."""
+    δ sits in the middle of the gap between two consecutive pair distances
+    nearest the ``delta_q`` quantile whose width is several times the f32
+    rounding of the engine's distances (measured against f64), so no pair
+    lies within rounding of δ: there the kernels' dot expansion and the
+    oracle's Σ(x−y)² may round apart (ROADMAP B1)."""
     rng = np.random.default_rng(seed)
     data = np.concatenate([
         rng.normal(loc=c, scale=1.0, size=(120, 5)) for c in (0.0, 4.0, 9.0)
-    ])
-    data = (np.rint(data * 16) / 16).astype(np.float32)
+    ]).astype(np.float32)
     d = np.asarray(distances.pairwise(jnp.asarray(data), jnp.asarray(data), metric))
-    delta = float(np.quantile(d[np.triu_indices(len(data), 1)], delta_q))
+    iu = np.triu_indices(len(data), 1)
+    d64 = data.astype(np.float64)
+    diff = np.abs(d64[:, None, :] - d64[None, :, :])
+    exact = {"l1": diff.sum(-1), "l2": np.sqrt((diff * diff).sum(-1)), "linf": diff.max(-1)}
+    guard = 4.0 * float(np.abs(d - exact[metric])[iu].max()) + 1e-6
+    ds = np.sort(d[iu])
+    k = int(delta_q * (len(ds) - 1))
+    wide = np.flatnonzero(np.diff(ds) > 2.0 * guard)
+    assert wide.size, "no pair-distance gap wider than f32 rounding"
+    g = wide[np.argmin(np.abs(wide - k))]
+    delta = float((ds[g] + ds[g + 1]) / 2.0)
     cfg = spjoin.JoinConfig(
         delta=delta, metric=metric, sampler=sampler, partitioner=partitioner,
         k=96, p=6, n_dims=3, seed=seed,
@@ -128,14 +138,16 @@ def test_join_on_minhash_metric(rng):
     assert np.array_equal(res.pairs, truth)
 
 
+@pytest.mark.parametrize("n,block", [(203, 32), (600, 256)])
 @pytest.mark.parametrize("cross", [False, True])
 @pytest.mark.parametrize("metric", ["l1", "l2", "linf", "jaccard_minhash"])
-def test_blocked_oracle_matches_dense(rng, metric, cross):
+def test_blocked_oracle_matches_dense(rng, metric, cross, n, block):
     """The blocked device oracle equals the dense matrix oracle with blocks
     smaller than n: several row and column blocks, the self-join diagonal,
-    and the overflow retry (far more hits per block than the first
-    capacity). Integer data keeps both L2 forms exact."""
-    x = rng.integers(0, 5, size=(203, 6)).astype(np.float32)
+    rows spanning several 128-column chunks, and the overflow retry (far
+    more hits per block than the first capacity). Integer data keeps both
+    L2 forms exact."""
+    x = rng.integers(0, 5, size=(n, 6)).astype(np.float32)
     y = rng.integers(0, 5, size=(77, 6)).astype(np.float32) if cross else None
     d = np.asarray(distances.pairwise(jnp.asarray(x), jnp.asarray(x), metric))
     delta = float(np.quantile(d, 0.2))
@@ -144,7 +156,7 @@ def test_blocked_oracle_matches_dense(rng, metric, cross):
         if cross
         else distances.brute_force_join(x, delta, metric)
     )
-    got = distances.oracle_pairs(x, delta, metric, y, block=32)
+    got = distances.oracle_pairs(x, delta, metric, y, block=block)
     assert got.shape[0] > 32
     np.testing.assert_array_equal(got, np.argwhere(dense))
 
