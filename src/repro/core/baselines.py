@@ -22,13 +22,11 @@ metric (verifications, balance std, cost model) is directly comparable.
 """
 from __future__ import annotations
 
-import time
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import cost_model, distances, sampling, spjoin
+from repro.core import cost_model, distances, sampling, spjoin, tracing
 
 Array = jnp.ndarray
 
@@ -61,53 +59,51 @@ def ball_join(
     data = jnp.asarray(data)
     n = data.shape[0]
 
-    t0 = time.perf_counter()
-    pivots = sampling.random_sample(key, data, min(n_pivots, n))
-    t_sample = time.perf_counter() - t0
+    with tracing.root("ball_join", rows=int(n)):
+        with tracing.span("ball_join.sample") as t_sample:
+            pivots = sampling.random_sample(key, data, min(n_pivots, n))
 
-    t0 = time.perf_counter()
-    d = distances.pairwise(data, pivots, metric)  # (n, p)
-    cells = jnp.argmin(d, axis=1).astype(jnp.int32)
-    nearest = d.min(axis=1, keepdims=True)
-    member = d <= nearest + 2.0 * delta  # (n, p) window rule
-    t_map = time.perf_counter() - t0
+        with tracing.span("ball_join.map") as t_map:
+            d = distances.pairwise(data, pivots, metric)  # (n, p)
+            cells = jnp.argmin(d, axis=1).astype(jnp.int32)
+            nearest = d.min(axis=1, keepdims=True)
+            member = d <= nearest + 2.0 * delta  # (n, p) window rule
+            cells_np = np.asarray(cells)  # the phase ends on the host
+            member_np = np.asarray(member)
 
-    t0 = time.perf_counter()
-    cells_np = np.asarray(cells)
-    member_np = np.asarray(member)
-    p = member_np.shape[1]
-    v_sizes = np.bincount(cells_np, minlength=p).astype(np.int64)
-    w_sizes = member_np.sum(0).astype(np.int64)
+        with tracing.span("ball_join.reduce") as t_verify:
+            p = member_np.shape[1]
+            v_sizes = np.bincount(cells_np, minlength=p).astype(np.int64)
+            w_sizes = member_np.sum(0).astype(np.int64)
 
-    metric_fn = distances.get_metric(metric)
-    n_verif = 0
-    chunks: list[np.ndarray] = []
-    for h in range(p):
-        v_idx = np.flatnonzero(cells_np == h)
-        w_idx = np.flatnonzero(member_np[:, h])
-        if v_idx.size == 0 or w_idx.size == 0:
-            continue
-        n_verif += int(v_idx.size) * int(w_idx.size)
-        dm = np.asarray(metric_fn.pairwise(data[v_idx], data[w_idx]))
-        hv, hw = np.nonzero(dm <= delta)
-        gi, gj = v_idx[hv], w_idx[hw]
-        cj = cells_np[gj]
-        keep = ((cj == h) & (gi < gj)) | (cj > h)
-        if return_pairs and keep.any():
-            chunks.append(np.stack([gi[keep], gj[keep]], axis=1))
-    pairs = (
-        np.unique(np.sort(np.concatenate(chunks), axis=1), axis=0)
-        if chunks
-        else np.zeros((0, 2), np.int64)
-    )
-    t_verify = time.perf_counter() - t0
+            metric_fn = distances.get_metric(metric)
+            n_verif = 0
+            chunks: list[np.ndarray] = []
+            for h in range(p):
+                v_idx = np.flatnonzero(cells_np == h)
+                w_idx = np.flatnonzero(member_np[:, h])
+                if v_idx.size == 0 or w_idx.size == 0:
+                    continue
+                n_verif += int(v_idx.size) * int(w_idx.size)
+                dm = np.asarray(metric_fn.pairwise(data[v_idx], data[w_idx]))
+                hv, hw = np.nonzero(dm <= delta)
+                gi, gj = v_idx[hv], w_idx[hw]
+                cj = cells_np[gj]
+                keep = ((cj == h) & (gi < gj)) | (cj > h)
+                if return_pairs and keep.any():
+                    chunks.append(np.stack([gi[keep], gj[keep]], axis=1))
+            pairs = (
+                np.unique(np.sort(np.concatenate(chunks), axis=1), axis=0)
+                if chunks
+                else np.zeros((0, 2), np.int64)
+            )
 
     return spjoin.JoinResult(
         pairs=pairs.astype(np.int64),
         n_verifications=n_verif,
         cost=cost_model.partition_cost(v_sizes, w_sizes),
         node_confidences=np.zeros((0,)),
-        sample_time_s=t_sample,
-        map_time_s=t_map,
-        verify_time_s=t_verify,
+        sample_time_s=t_sample.seconds,
+        map_time_s=t_map.seconds,
+        verify_time_s=t_verify.seconds,
     )

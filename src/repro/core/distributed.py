@@ -64,7 +64,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core import cost_model, distances, expfam, gof, mapping, partition, sampling
+from repro.core import cost_model, distances, expfam, gof, mapping, partition, sampling, tracing
 from repro.core import placement as placement_lib
 from repro.core import verify as verify_lib
 from repro.kernels import ops as kops
@@ -1349,46 +1349,57 @@ class DistIndex:
         if q_np.shape[0] == 0:
             return np.zeros((0, 2), np.int64)
         M = self.n_devices
-        sharding = NamedSharding(self.mesh, P(self.axis))
-        q_arr, valid, ids, _ = _pad_shard_set(jnp.asarray(q_np), M, sharding)
+        with tracing.root("serve.query_batch", n_queries=int(q_np.shape[0])):
+            with tracing.span("serve.put"):
+                sharding = NamedSharding(self.mesh, P(self.axis))
+                q_arr, valid, ids, _ = _pad_shard_set(jnp.asarray(q_np), M, sharding)
 
-        # Exact-fit W capacity from a host routing pass (same fused map path
-        # as the stage, so counts can never disagree), quantized up to a
-        # power of two so repeat batches reuse the compiled stage.
-        _, member = idx.route(q_np, delta)
-        n_tot = int(q_arr.shape[0])
-        per = n_tot // M
-        mem_pad = np.zeros((n_tot, idx.p), bool)
-        mem_pad[: q_np.shape[0]] = member
-        w_cnt = mem_pad.reshape(M, per, idx.p).sum(1)  # (M, p)
-        w_slot = w_cnt[:, np.clip(self.pl.slot_cell, 0, None)]
-        w_slot[:, self.pl.slot_cell < 0] = 0
-        exact = int(w_slot.max(initial=1))
-        cap_w = 1 << max(exact - 1, 1).bit_length()  # next pow2, ≥ 2
+            # Exact-fit W capacity from a host routing pass (same fused map path
+            # as the stage, so counts can never disagree), quantized up to a
+            # power of two so repeat batches reuse the compiled stage.
+            with tracing.span("serve.route") as route:
+                _, member = idx.route(q_np, delta)
+                n_tot = int(q_arr.shape[0])
+                per = n_tot // M
+                mem_pad = np.zeros((n_tot, idx.p), bool)
+                mem_pad[: q_np.shape[0]] = member
+                w_cnt = mem_pad.reshape(M, per, idx.p).sum(1)  # (M, p)
+                w_slot = w_cnt[:, np.clip(self.pl.slot_cell, 0, None)]
+                w_slot[:, self.pl.slot_cell < 0] = 0
+                exact = int(w_slot.max(initial=1))
+                cap_w = 1 << max(exact - 1, 1).bit_length()  # next pow2, ≥ 2
+                route.add(n_routed=int(w_cnt.sum()), cap_w=cap_w)
 
-        delta_bound = None
-        if self.prune == "pivot":
-            # Scale-aware fp band; the query magnitude is quantized up to a
-            # power of two so the (static) band doesn't recompile per batch.
-            q_abs = float(np.abs(q_np).max(initial=0.0))
-            q_pow = float(2.0 ** np.ceil(np.log2(max(q_abs, 1e-9))))
-            x_abs = max(self._x_abs, q_pow)
-            delta_bound = kref.prune_delta(
-                delta, idx.metric, x_abs, int(idx.data.shape[1])
-            )
+            with tracing.span("serve.stage") as stage:
+                delta_bound = None
+                if self.prune == "pivot":
+                    # Scale-aware fp band; the query magnitude is quantized up
+                    # to a power of two so the (static) band doesn't recompile
+                    # per batch.
+                    q_abs = float(np.abs(q_np).max(initial=0.0))
+                    q_pow = float(2.0 ** np.ceil(np.log2(max(q_abs, 1e-9))))
+                    x_abs = max(self._x_abs, q_pow)
+                    delta_bound = kref.prune_delta(
+                        delta, idx.metric, x_abs, int(idx.data.shape[1])
+                    )
+                n_stages = len(self._stages)
+                fn = self._stage(delta, cap_w, delta_bound)
+                stage.add(compiled=int(len(self._stages) > n_stages))
+                out = fn(self.fv, self.fv_ids, q_arr, valid, ids)
 
-        out = self._stage(delta, cap_w, delta_bound)(
-            self.fv, self.fv_ids, q_arr, valid, ids
-        )
-        assert int(np.asarray(out["overflow"]).sum()) == 0, "serve W overflow"
-        masks = np.asarray(out["masks"])  # (n_slots, cap_v, M*cap_w)
-        w_ids = np.asarray(out["w_ids"]).reshape(masks.shape[0], -1)
-        slot, vi, wi = np.nonzero(masks)
-        if slot.size == 0:
-            return np.zeros((0, 2), np.int64)
-        gi = self._fv_ids_host[slot, vi]
-        gj = w_ids[slot, wi]
-        return np.unique(np.stack([gi, gj], axis=1), axis=0).astype(np.int64)
+            with tracing.span("serve.readback") as readback:
+                assert int(np.asarray(out["overflow"]).sum()) == 0, "serve W overflow"
+                masks = np.asarray(out["masks"])  # (n_slots, cap_v, M*cap_w)
+                w_ids = np.asarray(out["w_ids"]).reshape(masks.shape[0], -1)
+                readback.add(mask_elems=int(masks.size))
+
+            with tracing.span("serve.unpack") as unpack:
+                slot, vi, wi = np.nonzero(masks)
+                gi = self._fv_ids_host[slot, vi]
+                gj = w_ids[slot, wi]
+                pairs = np.unique(np.stack([gi, gj], axis=1), axis=0).astype(np.int64)
+                unpack.add(n_hits=int(slot.size), n_pairs=int(pairs.shape[0]))
+        return pairs
 
     def _repin(self) -> None:
         """Re-lay the host index out on the mesh after an absorb (or a

@@ -49,14 +49,13 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
-import time
 from typing import TYPE_CHECKING
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import cost_model, distances, mapping, partition, spjoin
+from repro.core import cost_model, distances, mapping, partition, spjoin, tracing
 from repro.core import placement as placement_lib
 from repro.core import verify as verify_lib
 from repro.kernels import ops as kops
@@ -324,17 +323,15 @@ class MetricIndex:
         """
         delta = self.delta if delta is None else float(delta)
         q_np = np.asarray(q, np.float32)
-        t0 = time.perf_counter()
-        q_coords, member = self.route(q_np, delta)
-        t_route = time.perf_counter() - t0
-
-        t0 = time.perf_counter()
-        pairs, vstats = verify_lib.verify_resident(
-            self.data, self.cells, self.v_lists, member, delta, self.metric,
-            config=self._engine_config(), data_w=q_np,
-            coords=self.coords, coords_w=q_coords,
-        )
-        t_verify = time.perf_counter() - t0
+        with tracing.root("index.query_batch", n_queries=int(q_np.shape[0])):
+            with tracing.span("index.route") as route:
+                q_coords, member = self.route(q_np, delta)
+            with tracing.span("index.verify") as verify:
+                pairs, vstats = verify_lib.verify_resident(
+                    self.data, self.cells, self.v_lists, member, delta, self.metric,
+                    config=self._engine_config(), data_w=q_np,
+                    coords=self.coords, coords_w=q_coords,
+                )
         if not with_stats:
             return pairs
         touched = int((member.sum(0) > 0).sum())
@@ -342,8 +339,8 @@ class MetricIndex:
             n_queries=int(q_np.shape[0]),
             n_routed=int(member.sum()),
             n_cells_touched=touched,
-            route_s=t_route,
-            verify_s=t_verify,
+            route_s=route.seconds,
+            verify_s=verify.seconds,
             verify=vstats,
         )
         return pairs, stats
@@ -598,47 +595,48 @@ class MetricIndex:
             return np.zeros((0, 2), np.int64), stats
 
         n_old = self.n_rows
-        t0 = time.perf_counter()
-        d_coords, d_cells, d_member_old = self._delta_route(d_np)
-        stats.route_s = time.perf_counter() - t0
+        with tracing.root("index.insert_batch", n_delta=stats.n_delta):
+            with tracing.span("index.route") as route:
+                d_coords, d_cells, d_member_old = self._delta_route(d_np)
 
-        t0 = time.perf_counter()
-        if _cross_pairs_fn is None:
-            cross, cstats = verify_lib.verify_resident(
-                self.data, self.cells, self.v_lists, d_member_old,
-                self.delta, self.metric, config=self._engine_config(),
-                data_w=d_np, coords=self.coords, coords_w=d_coords,
-            )
-            stats.cross_verify = cstats
-        else:
-            cross = np.asarray(_cross_pairs_fn(d_np), np.int64).reshape(-1, 2)
-        self_local, sstats, new_lo, new_hi, member_new = self._delta_self_pairs(
-            d_np, d_coords, d_cells
-        )
-        stats.self_verify = sstats
-        stats.verify_s = time.perf_counter() - t0
-        stats.n_cross_pairs = int(cross.shape[0])
-        stats.n_self_pairs = int(self_local.shape[0])
+            with tracing.span("index.verify") as verify:
+                if _cross_pairs_fn is None:
+                    cross, cstats = verify_lib.verify_resident(
+                        self.data, self.cells, self.v_lists, d_member_old,
+                        self.delta, self.metric, config=self._engine_config(),
+                        data_w=d_np, coords=self.coords, coords_w=d_coords,
+                    )
+                    stats.cross_verify = cstats
+                else:
+                    cross = np.asarray(_cross_pairs_fn(d_np), np.int64).reshape(-1, 2)
+                self_local, sstats, new_lo, new_hi, member_new = self._delta_self_pairs(
+                    d_np, d_coords, d_cells
+                )
+            stats.self_verify = sstats
+            stats.n_cross_pairs = int(cross.shape[0])
+            stats.n_self_pairs = int(self_local.shape[0])
 
-        # Globalize: cross pairs are (i ∈ resident, j ∈ delta) — already
-        # i < n_old + j; ΔΔ pairs shift both columns above the resident set.
-        chunks = []
-        if cross.shape[0]:
-            chunks.append(
-                np.stack([cross[:, 0], n_old + cross[:, 1]], axis=1)
-            )
-        if self_local.shape[0]:
-            chunks.append(self_local + n_old)
-        if chunks:
-            pairs = np.unique(np.concatenate(chunks), axis=0).astype(np.int64)
-        else:
-            pairs = np.zeros((0, 2), np.int64)
-        stats.n_new_pairs = int(pairs.shape[0])
+            # Globalize: cross pairs are (i ∈ resident, j ∈ delta) — already
+            # i < n_old + j; ΔΔ pairs shift both columns above the resident set.
+            chunks = []
+            if cross.shape[0]:
+                chunks.append(
+                    np.stack([cross[:, 0], n_old + cross[:, 1]], axis=1)
+                )
+            if self_local.shape[0]:
+                chunks.append(self_local + n_old)
+            if chunks:
+                pairs = np.unique(np.concatenate(chunks), axis=0).astype(np.int64)
+            else:
+                pairs = np.zeros((0, 2), np.int64)
+            stats.n_new_pairs = int(pairs.shape[0])
 
-        t0 = time.perf_counter()
-        self._absorb(d_np, d_coords, d_cells, member_new, new_lo, new_hi)
-        self._drift_step(stats, rt, rs, rebuild_cfg)
-        stats.update_s = time.perf_counter() - t0
+            with tracing.span("index.update") as update:
+                self._absorb(d_np, d_coords, d_cells, member_new, new_lo, new_hi)
+                self._drift_step(stats, rt, rs, rebuild_cfg)
+        stats.route_s = route.seconds
+        stats.verify_s = verify.seconds
+        stats.update_s = update.seconds
         return pairs, stats
 
     # ----------------------------------------------------------- distributed
@@ -890,87 +888,87 @@ def build_index(
     (``fit_node_stats`` → ``draw_pivots`` → ``build_plan``), so a fixed seed
     yields the identical partition geometry the one-shot join would use.
     """
-    t_start = time.perf_counter()
-    key = jax.random.PRNGKey(cfg.seed)
-    shards = spjoin._as_shards(data, n_nodes)
-    allx = jnp.concatenate(shards, axis=0) if shards else jnp.asarray(data)
+    with tracing.root("index.build") as build:
+        key = jax.random.PRNGKey(cfg.seed)
+        shards = spjoin._as_shards(data, n_nodes)
+        allx = jnp.concatenate(shards, axis=0) if shards else jnp.asarray(data)
 
-    # ---- sampling phase (once, at build) ---------------------------------
-    k_sample, k_anchor = jax.random.split(key)
-    node_stats = spjoin.fit_node_stats(shards, cfg.t_cells)
-    pivots = spjoin.draw_pivots(k_sample, shards, node_stats, cfg)
+        # ---- sampling phase (once, at build) ---------------------------------
+        k_sample, k_anchor = jax.random.split(key)
+        node_stats = spjoin.fit_node_stats(shards, cfg.t_cells)
+        pivots = spjoin.draw_pivots(k_sample, shards, node_stats, cfg)
 
-    # ---- map-phase control plane (once, at build) ------------------------
-    plan, smap = spjoin.build_plan(k_anchor, pivots, cfg)
-    fused = cfg.map_fused and kops.supports_kernel(cfg.metric)
-    backend = (
-        kops.resolve_backend(cfg.backend, cfg.metric)
-        if kops.supports_kernel(cfg.metric)
-        else "numpy"
-    )
-    if fused:
-        x_mapped, cells, _ = kops.map_assign(
-            allx, smap.anchors, plan.kernel_lo, plan.kernel_hi,
-            plan.whole_lo, plan.whole_hi, cfg.metric, backend=backend,
-            want="cells",
+        # ---- map-phase control plane (once, at build) ------------------------
+        plan, smap = spjoin.build_plan(k_anchor, pivots, cfg)
+        fused = cfg.map_fused and kops.supports_kernel(cfg.metric)
+        backend = (
+            kops.resolve_backend(cfg.backend, cfg.metric)
+            if kops.supports_kernel(cfg.metric)
+            else "numpy"
         )
-    else:
-        x_mapped = smap(allx)
-        cells = partition.assign_kernel(plan, x_mapped)
-    box_lo, box_hi = _base_boxes(plan, x_mapped, cells, cfg.tighten)
+        if fused:
+            x_mapped, cells, _ = kops.map_assign(
+                allx, smap.anchors, plan.kernel_lo, plan.kernel_hi,
+                plan.whole_lo, plan.whole_hi, cfg.metric, backend=backend,
+                want="cells",
+            )
+        else:
+            x_mapped = smap(allx)
+            cells = partition.assign_kernel(plan, x_mapped)
+        box_lo, box_hi = _base_boxes(plan, x_mapped, cells, cfg.tighten)
 
-    # ---- placement plan (cost-model loads from the pivots alone) ---------
-    n_dev = int(n_devices or max(len(shards), 1))
-    piv_mapped = np.asarray(smap(pivots), np.float32)
-    piv_plan = partition.PartitionPlan(
-        plan.kernel_lo, plan.kernel_hi,
-        jnp.asarray(box_lo - np.float32(cfg.delta)),
-        jnp.asarray(box_hi + np.float32(cfg.delta)),
-        cfg.delta,
-    )
-    piv_cells = np.asarray(partition.assign_kernel(piv_plan, jnp.asarray(piv_mapped)))
-    piv_member = np.asarray(
-        partition.whole_membership(piv_plan, jnp.asarray(piv_mapped))
-    )
-    prune_active = verify_lib.resolve_prune(cfg.prune, cfg.metric, True) == "pivot"
-    cell_loads, _, _, _ = placement_lib.planner_inputs(
-        piv_mapped, piv_cells, piv_member,
-        int(allx.shape[0]), int(allx.shape[0]), cfg.delta, prune_active,
-    )
-    pl = placement_lib.plan_placement(cell_loads, n_dev, strategy=cfg.placement)
+        # ---- placement plan (cost-model loads from the pivots alone) ---------
+        n_dev = int(n_devices or max(len(shards), 1))
+        piv_mapped = np.asarray(smap(pivots), np.float32)
+        piv_plan = partition.PartitionPlan(
+            plan.kernel_lo, plan.kernel_hi,
+            jnp.asarray(box_lo - np.float32(cfg.delta)),
+            jnp.asarray(box_hi + np.float32(cfg.delta)),
+            cfg.delta,
+        )
+        piv_cells = np.asarray(partition.assign_kernel(piv_plan, jnp.asarray(piv_mapped)))
+        piv_member = np.asarray(
+            partition.whole_membership(piv_plan, jnp.asarray(piv_mapped))
+        )
+        prune_active = verify_lib.resolve_prune(cfg.prune, cfg.metric, True) == "pivot"
+        cell_loads, _, _, _ = placement_lib.planner_inputs(
+            piv_mapped, piv_cells, piv_member,
+            int(allx.shape[0]), int(allx.shape[0]), cfg.delta, prune_active,
+        )
+        pl = placement_lib.plan_placement(cell_loads, n_dev, strategy=cfg.placement)
 
-    idx = MetricIndex(
-        metric=cfg.metric,
-        delta=float(cfg.delta),
-        n_dims=int(smap.n_dims),
-        tighten=bool(cfg.tighten),
-        backend=backend,
-        prune=cfg.prune,
-        map_fused=bool(fused),
-        tile_v=cfg.tile_v,
-        tile_w=cfg.tile_w,
-        seed=cfg.seed,
-        placement_strategy=cfg.placement,
-        n_devices=n_dev,
-        data=np.asarray(allx, np.float32),
-        coords=np.asarray(x_mapped, np.float32),
-        cells=np.asarray(cells, np.int32),
-        pivots=np.asarray(pivots, np.float32),
-        anchors=np.asarray(smap.anchors, np.float32),
-        kernel_lo=np.asarray(plan.kernel_lo, np.float32),
-        kernel_hi=np.asarray(plan.kernel_hi, np.float32),
-        box_lo=box_lo,
-        box_hi=box_hi,
-        placement=pl,
-        node_confidences=np.array([st.confidence for st in node_stats]),
-        n_base=int(allx.shape[0]),
-        observed_w=_member_counts(
-            np.asarray(x_mapped, np.float32),
-            (box_lo - np.float32(cfg.delta)).astype(np.float32),
-            (box_hi + np.float32(cfg.delta)).astype(np.float32),
-        ),
-    )
-    idx.build_s = time.perf_counter() - t_start
+        idx = MetricIndex(
+            metric=cfg.metric,
+            delta=float(cfg.delta),
+            n_dims=int(smap.n_dims),
+            tighten=bool(cfg.tighten),
+            backend=backend,
+            prune=cfg.prune,
+            map_fused=bool(fused),
+            tile_v=cfg.tile_v,
+            tile_w=cfg.tile_w,
+            seed=cfg.seed,
+            placement_strategy=cfg.placement,
+            n_devices=n_dev,
+            data=np.asarray(allx, np.float32),
+            coords=np.asarray(x_mapped, np.float32),
+            cells=np.asarray(cells, np.int32),
+            pivots=np.asarray(pivots, np.float32),
+            anchors=np.asarray(smap.anchors, np.float32),
+            kernel_lo=np.asarray(plan.kernel_lo, np.float32),
+            kernel_hi=np.asarray(plan.kernel_hi, np.float32),
+            box_lo=box_lo,
+            box_hi=box_hi,
+            placement=pl,
+            node_confidences=np.array([st.confidence for st in node_stats]),
+            n_base=int(allx.shape[0]),
+            observed_w=_member_counts(
+                np.asarray(x_mapped, np.float32),
+                (box_lo - np.float32(cfg.delta)).astype(np.float32),
+                (box_hi + np.float32(cfg.delta)).astype(np.float32),
+            ),
+        )
+    idx.build_s = build.seconds
     return idx
 
 

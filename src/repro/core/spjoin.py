@@ -23,7 +23,6 @@ both g and h, hence exactly once after the rule.
 from __future__ import annotations
 
 import dataclasses
-import time
 import warnings
 from typing import Sequence
 
@@ -31,7 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import cost_model, distances, expfam, gof, mapping, partition, sampling
+from repro.core import cost_model, distances, expfam, gof, mapping, partition, sampling, tracing
 from repro.core import placement as placement_lib
 from repro.core import verify as verify_lib
 from repro.kernels import ops as kops
@@ -90,6 +89,9 @@ class JoinResult:
     n_verifications: int  # Σ_h |V_h|·|W_h| actually computed
     cost: cost_model.PartitionCost
     node_confidences: np.ndarray
+    # Host seconds of the phases' spans (core.tracing): the map phase ends
+    # with the host copies of its cells and membership, so it includes the
+    # map kernel's wait and the reduce phase does not.
     sample_time_s: float
     map_time_s: float
     verify_time_s: float
@@ -220,143 +222,145 @@ def join(
     if s is data:
         s = None  # R = S aliasing: the canonical semantics is the self-join
     cross = s is not None
-    key = jax.random.PRNGKey(cfg.seed)
-    shards = _as_shards(data, n_nodes)
-    allx = jnp.concatenate(shards, axis=0) if shards else jnp.asarray(data)
+    with tracing.root("spjoin.join") as root:
+        key = jax.random.PRNGKey(cfg.seed)
+        shards = _as_shards(data, n_nodes)
+        allx = jnp.concatenate(shards, axis=0) if shards else jnp.asarray(data)
 
-    s_shards: list[Array] = _as_shards(s, n_nodes) if cross else []
-    s_all = (
-        jnp.concatenate(s_shards, axis=0)
-        if s_shards
-        else jnp.zeros((0, allx.shape[1]), allx.dtype)
-    )
-
-    # ---- sampling phase -------------------------------------------------
-    t0 = time.perf_counter()
-    k_sample, k_anchor = jax.random.split(key)
-    # R∪S: pivots must cover both distributions (empty-set shards carry no
-    # distribution and are skipped — the self path keeps its exact shard list).
-    fit_shards = (
-        [sh for sh in shards + s_shards if sh.shape[0] > 0] if cross else shards
-    )
-    node_stats = fit_node_stats(fit_shards, cfg.t_cells)
-    pivots = draw_pivots(k_sample, fit_shards, node_stats, cfg)
-    t_sample = time.perf_counter() - t0
-
-    # ---- map phase -------------------------------------------------------
-    t0 = time.perf_counter()
-    plan, smap = build_plan(k_anchor, pivots, cfg)
-    # Fused single-pass map kernel (space map + assign + packed membership)
-    # when the metric has one; reference-only metrics (angular,
-    # jaccard_minhash) keep the two-pass jnp path — capability, not error,
-    # exactly like backend dispatch. Outputs are byte-identical either way.
-    fused = cfg.map_fused and kops.supports_kernel(cfg.metric)
-    assign_backend = cfg.backend if fused else None
-    if fused:
-        # Membership is only worth computing in the first pass when the whole
-        # boxes are final (no tighten, self-join) — otherwise request cells
-        # only and pay for exactly one membership sweep below, same total
-        # containment work as the legacy path.
-        want = "both" if (not cfg.tighten and not cross) else "cells"
-        x_mapped, cells, bits = kops.map_assign(
-            allx, smap.anchors, plan.kernel_lo, plan.kernel_hi,
-            plan.whole_lo, plan.whole_hi, cfg.metric, backend=cfg.backend,
-            want=want,
+        s_shards: list[Array] = _as_shards(s, n_nodes) if cross else []
+        s_all = (
+            jnp.concatenate(s_shards, axis=0)
+            if s_shards
+            else jnp.zeros((0, allx.shape[1]), allx.dtype)
         )
-    else:
-        x_mapped = smap(allx)
-        cells = partition.assign_kernel(plan, x_mapped)
-        bits = None
-    if cfg.tighten:
-        # Kernel-cell MBBs come from R only (V rows); Lemma 4 still covers
-        # every S partner: it lies within L∞ δ of an R member of the cell.
-        plan = partition.tighten(plan, x_mapped, cells)
-    s_mapped = None
-    if cross:
-        if s_all.shape[0] == 0:
-            s_mapped = jnp.zeros((0, smap.n_dims), jnp.float32)
-            member = jnp.zeros((0, plan.p), bool)
-        elif fused:
-            # Same fused pass (and fp algorithm) as the R side — a borderline
-            # S coordinate must not land on a different side of a whole-box
-            # edge than R's kernel-computed MBB implies.
-            s_mapped, _, s_bits = kops.map_assign(
-                s_all, smap.anchors, plan.kernel_lo, plan.kernel_hi,
-                plan.whole_lo, plan.whole_hi, cfg.metric, backend=cfg.backend,
-                want="member",
+        root.add(rows=int(allx.shape[0]) + int(s_all.shape[0]))
+
+        # ---- sampling phase -------------------------------------------------
+        with tracing.span("spjoin.sample") as t_sample:
+            k_sample, k_anchor = jax.random.split(key)
+            # R∪S: pivots must cover both distributions (empty-set shards carry no
+            # distribution and are skipped — the self path keeps its exact shard list).
+            fit_shards = (
+                [sh for sh in shards + s_shards if sh.shape[0] > 0] if cross else shards
             )
-            member = kops.unpack_membership(s_bits, plan.p)
-        else:
-            s_mapped = smap(s_all)
-            member = partition.whole_membership(plan, s_mapped)
-    elif fused and not cfg.tighten:
-        # The fused pass already produced membership for the final boxes.
-        member = kops.unpack_membership(bits, plan.p)
-    else:
-        member = partition.whole_membership(plan, x_mapped, backend=assign_backend)
-    t_map = time.perf_counter() - t0
+            node_stats = fit_node_stats(fit_shards, cfg.t_cells)
+            pivots = draw_pivots(k_sample, fit_shards, node_stats, cfg)
 
-    # ---- reduce phase: streaming tiled verify engine ---------------------
-    # The mapped coordinates double as the verify phase's pivot filter
-    # (prune="pivot"): the map phase already paid for them, the engine only
-    # gathers them into tiles alongside the payload.
-    t0 = time.perf_counter()
-    cells_np = np.asarray(cells)
-    member_np = np.asarray(member)
-    stats = partition.partition_stats(cells_np, member_np)
-    pairs, vstats = verify_lib.verify_pairs(
-        allx, cells_np, member_np, cfg.delta, cfg.metric,
-        config=cfg.engine_config(), return_pairs=return_pairs,
-        data_w=s_all if cross else None,
-        coords=x_mapped, coords_w=s_mapped,
-    )
-    t_verify = time.perf_counter() - t0
+        # ---- map phase -------------------------------------------------------
+        with tracing.span("spjoin.map") as t_map:
+            plan, smap = build_plan(k_anchor, pivots, cfg)
+            # Fused single-pass map kernel (space map + assign + packed membership)
+            # when the metric has one; reference-only metrics (angular,
+            # jaccard_minhash) keep the two-pass jnp path — capability, not error,
+            # exactly like backend dispatch. Outputs are byte-identical either way.
+            fused = cfg.map_fused and kops.supports_kernel(cfg.metric)
+            assign_backend = cfg.backend if fused else None
+            if fused:
+                # Membership is only worth computing in the first pass when the whole
+                # boxes are final (no tighten, self-join) — otherwise request cells
+                # only and pay for exactly one membership sweep below, same total
+                # containment work as the legacy path.
+                want = "both" if (not cfg.tighten and not cross) else "cells"
+                x_mapped, cells, bits = kops.map_assign(
+                    allx, smap.anchors, plan.kernel_lo, plan.kernel_hi,
+                    plan.whole_lo, plan.whole_hi, cfg.metric, backend=cfg.backend,
+                    want=want,
+                )
+            else:
+                x_mapped = smap(allx)
+                cells = partition.assign_kernel(plan, x_mapped)
+                bits = None
+            if cfg.tighten:
+                # Kernel-cell MBBs come from R only (V rows); Lemma 4 still covers
+                # every S partner: it lies within L∞ δ of an R member of the cell.
+                plan = partition.tighten(plan, x_mapped, cells)
+            s_mapped = None
+            if cross:
+                if s_all.shape[0] == 0:
+                    s_mapped = jnp.zeros((0, smap.n_dims), jnp.float32)
+                    member = jnp.zeros((0, plan.p), bool)
+                elif fused:
+                    # Same fused pass (and fp algorithm) as the R side — a borderline
+                    # S coordinate must not land on a different side of a whole-box
+                    # edge than R's kernel-computed MBB implies.
+                    s_mapped, _, s_bits = kops.map_assign(
+                        s_all, smap.anchors, plan.kernel_lo, plan.kernel_hi,
+                        plan.whole_lo, plan.whole_hi, cfg.metric, backend=cfg.backend,
+                        want="member",
+                    )
+                    member = kops.unpack_membership(s_bits, plan.p)
+                else:
+                    s_mapped = smap(s_all)
+                    member = partition.whole_membership(plan, s_mapped)
+            elif fused and not cfg.tighten:
+                # The fused pass already produced membership for the final boxes.
+                member = kops.unpack_membership(bits, plan.p)
+            else:
+                member = partition.whole_membership(plan, x_mapped, backend=assign_backend)
+            # The phase ends on the host: reading its results here makes the map
+            # time include the map kernel's wait, and keeps it out of the reduce.
+            cells_np = np.asarray(cells)
+            member_np = np.asarray(member)
 
-    if cross:
-        cost = cost_model.rs_partition_cost(
-            stats["v_sizes"], stats["w_sizes"], int(s_all.shape[0])
+        # ---- reduce phase: streaming tiled verify engine ---------------------
+        # The mapped coordinates double as the verify phase's pivot filter
+        # (prune="pivot"): the map phase already paid for them, the engine only
+        # gathers them into tiles alongside the payload.
+        with tracing.span("spjoin.reduce") as t_reduce:
+            stats = partition.partition_stats(cells_np, member_np)
+            pairs, vstats = verify_lib.verify_pairs(
+                allx, cells_np, member_np, cfg.delta, cfg.metric,
+                config=cfg.engine_config(), return_pairs=return_pairs,
+                data_w=s_all if cross else None,
+                coords=x_mapped, coords_w=s_mapped,
+            )
+
+        with tracing.span("spjoin.report"):
+            if cross:
+                cost = cost_model.rs_partition_cost(
+                    stats["v_sizes"], stats["w_sizes"], int(s_all.shape[0])
+                )
+            else:
+                cost = cost_model.partition_cost(stats["v_sizes"], stats["w_sizes"])
+
+            # ---- reduce-placement report (same cost-model loads + planner as the
+            # distributed executor; single-host, so the plan is telemetry only) ----
+            piv_mapped = np.asarray(smap(pivots), np.float32)
+            piv_cells = np.asarray(partition.assign_kernel(plan, jnp.asarray(piv_mapped)))
+            piv_member = np.asarray(partition.whole_membership(plan, jnp.asarray(piv_mapped)))
+            cell_loads, _, _, _ = placement_lib.planner_inputs(
+                piv_mapped, piv_cells, piv_member,
+                int(allx.shape[0]), int(s_all.shape[0]) if cross else int(allx.shape[0]),
+                cfg.delta, vstats.prune == "pivot",
+            )
+            pl = placement_lib.plan_placement(
+                cell_loads, max(len(shards), 1), strategy=cfg.placement
+            )
+            cap_saved = placement_lib.capacity_saved_bytes(
+                pl, stats["v_sizes"][None, :], stats["w_sizes"][None, :],
+                placement_lib.dispatch_row_bytes(
+                    int(allx.shape[1]), smap.n_dims, vstats.prune == "pivot"
+                ),
+            )
+            dev_loads = pl.device_loads
+
+        return JoinResult(
+            pairs=pairs,
+            n_verifications=vstats.n_verifications,
+            cost=cost,
+            node_confidences=np.array([st.confidence for st in node_stats]),
+            sample_time_s=t_sample.seconds,
+            map_time_s=t_map.seconds,
+            verify_time_s=t_reduce.seconds,
+            verify_stats=vstats,
+            per_cell_verified=(stats["v_sizes"] * stats["w_sizes"]).astype(np.int64),
+            placement_plan=pl,
+            device_loads=dev_loads,
+            balance_std=float(dev_loads.std()),
+            makespan_ratio=float(dev_loads.max(initial=0.0) / max(dev_loads.mean(), 1e-9)),
+            capacity_saved_bytes=int(cap_saved),
+            map_fused=fused,
         )
-    else:
-        cost = cost_model.partition_cost(stats["v_sizes"], stats["w_sizes"])
-
-    # ---- reduce-placement report (same cost-model loads + planner as the
-    # distributed executor; single-host, so the plan is telemetry only) ----
-    piv_mapped = np.asarray(smap(pivots), np.float32)
-    piv_cells = np.asarray(partition.assign_kernel(plan, jnp.asarray(piv_mapped)))
-    piv_member = np.asarray(partition.whole_membership(plan, jnp.asarray(piv_mapped)))
-    cell_loads, _, _, _ = placement_lib.planner_inputs(
-        piv_mapped, piv_cells, piv_member,
-        int(allx.shape[0]), int(s_all.shape[0]) if cross else int(allx.shape[0]),
-        cfg.delta, vstats.prune == "pivot",
-    )
-    pl = placement_lib.plan_placement(
-        cell_loads, max(len(shards), 1), strategy=cfg.placement
-    )
-    cap_saved = placement_lib.capacity_saved_bytes(
-        pl, stats["v_sizes"][None, :], stats["w_sizes"][None, :],
-        placement_lib.dispatch_row_bytes(
-            int(allx.shape[1]), smap.n_dims, vstats.prune == "pivot"
-        ),
-    )
-    dev_loads = pl.device_loads
-
-    return JoinResult(
-        pairs=pairs,
-        n_verifications=vstats.n_verifications,
-        cost=cost,
-        node_confidences=np.array([st.confidence for st in node_stats]),
-        sample_time_s=t_sample,
-        map_time_s=t_map,
-        verify_time_s=t_verify,
-        verify_stats=vstats,
-        per_cell_verified=(stats["v_sizes"] * stats["w_sizes"]).astype(np.int64),
-        placement_plan=pl,
-        device_loads=dev_loads,
-        balance_std=float(dev_loads.std()),
-        makespan_ratio=float(dev_loads.max(initial=0.0) / max(dev_loads.mean(), 1e-9)),
-        capacity_saved_bytes=int(cap_saved),
-        map_fused=fused,
-    )
 
 
 class IncrementalJoin:

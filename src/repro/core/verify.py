@@ -122,7 +122,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import cost_model, distances
+from repro.core import cost_model, distances, tracing
 from repro.kernels import ops as kops
 from repro.kernels import ref
 
@@ -651,33 +651,34 @@ def _flush_window_batch(
     vmapped verify per bucket shape, emit hits with one batched nonzero.
     Identical per-tile masks to the immediate path by construction (vmap of
     the same :func:`verify_tile`)."""
-    fn = _batched_tile_verify(metric, cross)
-    groups: dict[tuple[int, int], list[int]] = {}
-    for i, t in enumerate(pending):
-        groups.setdefault((t[0].shape[0], t[1].shape[0]), []).append(i)
-    batches = []
-    for idxs in groups.values():
-        xv = np.stack([pending[i][0] for i in idxs])
-        xw = np.stack([pending[i][1] for i in idxs])
-        vids = np.stack([pending[i][2] for i in idxs])
-        wids = np.stack([pending[i][3] for i in idxs])
-        wcs = np.stack([pending[i][4] for i in idxs])
-        hs = np.fromiter((pending[i][5] for i in idxs), np.int64, len(idxs))
-        batches.append((vids, wids, fn(xv, xw, vids, wids, wcs, hs, float(delta))))
-    # ONE device->host sync for the whole flush, after every bucket-shape
-    # batch has been enqueued — not one blocking readback per batch (the
-    # prune_band idiom).
-    outs = jax.device_get([b[2] for b in batches])
-    for (vids, wids, _), out in zip(batches, outs):
-        bi, vi, wi = out.nonzero()
-        stats.n_hits += int(bi.size)
-        if return_pairs and bi.size:
-            # Padding lanes carry id -1 but can never be hits (pair
-            # validity is ANDed inside verify_tile), so the gathered ids
-            # are always real rows.
-            chunks.append(
-                np.stack([vids[bi, vi], wids[bi, wi]], axis=1).astype(np.int64)
-            )
+    with tracing.span("verify.flush"):
+        fn = _batched_tile_verify(metric, cross)
+        groups: dict[tuple[int, int], list[int]] = {}
+        for i, t in enumerate(pending):
+            groups.setdefault((t[0].shape[0], t[1].shape[0]), []).append(i)
+        batches = []
+        for idxs in groups.values():
+            xv = np.stack([pending[i][0] for i in idxs])
+            xw = np.stack([pending[i][1] for i in idxs])
+            vids = np.stack([pending[i][2] for i in idxs])
+            wids = np.stack([pending[i][3] for i in idxs])
+            wcs = np.stack([pending[i][4] for i in idxs])
+            hs = np.fromiter((pending[i][5] for i in idxs), np.int64, len(idxs))
+            batches.append((vids, wids, fn(xv, xw, vids, wids, wcs, hs, float(delta))))
+        # ONE device->host sync for the whole flush, after every bucket-shape
+        # batch has been enqueued — not one blocking readback per batch (the
+        # prune_band idiom).
+        outs = jax.device_get([b[2] for b in batches])
+        for (vids, wids, _), out in zip(batches, outs):
+            bi, vi, wi = out.nonzero()
+            stats.n_hits += int(bi.size)
+            if return_pairs and bi.size:
+                # Padding lanes carry id -1 but can never be hits (pair
+                # validity is ANDed inside verify_tile), so the gathered ids
+                # are always real rows.
+                chunks.append(
+                    np.stack([vids[bi, vi], wids[bi, wi]], axis=1).astype(np.int64)
+                )
     pending.clear()
 
 
@@ -798,197 +799,219 @@ def verify_cell_lists(
             continue
         stats.n_cells += 1
         stats.n_verifications += int(v_idx.size) * int(w_idx.size)
-        w_coord0 = None
-        if prune != "none":
-            # Window refinement (module docstring): order both sides by ONE
-            # mapped coordinate, so V tiles become coordinate bands and the
-            # binary search below slices each one's W range down to the
-            # ± delta_bound window. Any 1-Lipschitz coordinate is sound, so
-            # pick the one this cell's W rows spread widest on — the kernel
-            # grid already localizes the partitioned coordinates, leaving
-            # them little window to cut. Pure reordering — the emitted pair
-            # SET is unchanged; everything sliced off is a provable non-hit.
-            wc_all = coords_w_np[w_idx]
-            sort_dim = int((wc_all.max(axis=0) - wc_all.min(axis=0)).argmax())
-            v_idx = v_idx[np.argsort(coords_np[v_idx, sort_dim], kind="stable")]
-            word = np.argsort(wc_all[:, sort_dim], kind="stable")
-            w_idx = w_idx[word]
-            # One gather per cell into sort order; every tile below is a
-            # contiguous slice of these buffers (window = contiguous range).
-            w_coords_cell = wc_all[word]
-            w_coord0 = w_coords_cell[:, sort_dim]
-            w_data_cell = data_w_np[w_idx]
-            w_cells_cell = None if cross else cells_np[w_idx]
-            v_coords_cell = coords_np[v_idx]
-            v_data_cell = data_np[v_idx]
-            w_tiles = None  # sliced per V tile from the surviving window
-        else:
-            # W tiles are prepared once per cell (not per V tile): the copies
-            # are O(|W_h|·m) — linear in cell size, like the input rows
-            # themselves — while only the pair product streams tile-by-tile.
-            w_tiles = _prep_w_tiles(w_idx, data_w_np, cells_np, None, cross, config)
-        for v0 in range(0, v_idx.size, config.tile_v):
-            vt = v_idx[v0 : v0 + config.tile_v]
-            cap_v = bucket_size(vt.size, config.tile_v, config.min_bucket)
-            pv = v_box = None
+        with tracing.span("verify.cell", v=int(v_idx.size), w=int(w_idx.size)):
+            w_coord0 = None
             if prune != "none":
-                v_coords = v_coords_cell[v0 : v0 + config.tile_v]
-                v_box = (v_coords.min(axis=0), v_coords.max(axis=0))
-                xv, vids = _pad_rows(v_data_cell[v0 : v0 + config.tile_v], vt, cap_v)
-                if prune == "pivot":  # per-pair bound rides into the tile
-                    pv = _pad_rows(v_coords, vt, cap_v)[0]
-                vc = v_coords[:, sort_dim]
-                lo = int(np.searchsorted(w_coord0, vc.min() - delta_bound, "left"))
-                hi = int(np.searchsorted(w_coord0, vc.max() + delta_bound, "right"))
-                # W rows outside [lo, hi) differ from every V row in this
-                # tile by more than delta_bound on one 1-Lipschitz coordinate
-                # — already above the L∞ lower bound, pruned with zero
-                # gather and zero dispatch.
-                stats.n_pruned += int(vt.size) * int(w_idx.size - (hi - lo))
-                if lo == hi:
-                    continue
-                w_tiles = _prep_w_tiles_sorted(
-                    w_idx, w_data_cell, w_cells_cell, w_coords_cell,
-                    lo, hi, config, need_pw=prune == "pivot",
-                )
+                # Window refinement (module docstring): order both sides by ONE
+                # mapped coordinate, so V tiles become coordinate bands and the
+                # binary search below slices each one's W range down to the
+                # ± delta_bound window. Any 1-Lipschitz coordinate is sound, so
+                # pick the one this cell's W rows spread widest on — the kernel
+                # grid already localizes the partitioned coordinates, leaving
+                # them little window to cut. Pure reordering — the emitted pair
+                # SET is unchanged; everything sliced off is a provable non-hit.
+                wc_all = coords_w_np[w_idx]
+                sort_dim = int((wc_all.max(axis=0) - wc_all.min(axis=0)).argmax())
+                v_idx = v_idx[np.argsort(coords_np[v_idx, sort_dim], kind="stable")]
+                word = np.argsort(wc_all[:, sort_dim], kind="stable")
+                w_idx = w_idx[word]
+                # One gather per cell into sort order; every tile below is a
+                # contiguous slice of these buffers (window = contiguous range).
+                w_coords_cell = wc_all[word]
+                w_coord0 = w_coords_cell[:, sort_dim]
+                w_data_cell = data_w_np[w_idx]
+                w_cells_cell = None if cross else cells_np[w_idx]
+                v_coords_cell = coords_np[v_idx]
+                v_data_cell = data_np[v_idx]
+                w_tiles = None  # sliced per V tile from the surviving window
             else:
-                xv, vids = _pad_gather(data_np, vt, cap_v)
-            for wt, cap_w, xw, wids, wc, pw, w_box in w_tiles:
-                n_valid = int(vt.size) * int(wt.size)
-                if v_box is not None and w_box is not None:
-                    # Bounding-box tile skip: interval arithmetic on the
-                    # mapped coordinates. The gap between the V and W boxes
-                    # lower-bounds every pair's L∞ bound, so a gap beyond
-                    # delta_bound means the whole tile is provable non-hits
-                    # — skipped before any dispatch, on every coordinate
-                    # (the window above only exploits the sort coordinate).
-                    gap = np.maximum(
-                        w_box[0] - v_box[1], v_box[0] - w_box[1]
-                    ).max()
-                    if gap > delta_bound:
-                        stats.n_pruned += n_valid
-                        stats.n_tiles_pruned += 1
+                # W tiles are prepared once per cell (not per V tile): the copies
+                # are O(|W_h|·m) — linear in cell size, like the input rows
+                # themselves — while only the pair product streams tile-by-tile.
+                with tracing.span("verify.w_tiles"):
+                    w_tiles = _prep_w_tiles(w_idx, data_w_np, cells_np, None, cross, config)
+            for v0 in range(0, v_idx.size, config.tile_v):
+                vt = v_idx[v0 : v0 + config.tile_v]
+                cap_v = bucket_size(vt.size, config.tile_v, config.min_bucket)
+                pv = v_box = None
+                if prune != "none":
+                    v_coords = v_coords_cell[v0 : v0 + config.tile_v]
+                    v_box = (v_coords.min(axis=0), v_coords.max(axis=0))
+                    xv, vids = _pad_rows(v_data_cell[v0 : v0 + config.tile_v], vt, cap_v)
+                    if prune == "pivot":  # per-pair bound rides into the tile
+                        pv = _pad_rows(v_coords, vt, cap_v)[0]
+                    vc = v_coords[:, sort_dim]
+                    lo = int(np.searchsorted(w_coord0, vc.min() - delta_bound, "left"))
+                    hi = int(np.searchsorted(w_coord0, vc.max() + delta_bound, "right"))
+                    # W rows outside [lo, hi) differ from every V row in this
+                    # tile by more than delta_bound on one 1-Lipschitz coordinate
+                    # — already above the L∞ lower bound, pruned with zero
+                    # gather and zero dispatch.
+                    stats.n_pruned += int(vt.size) * int(w_idx.size - (hi - lo))
+                    if lo == hi:
                         continue
-                premask = None
-                if emit == "mask" and prune == "pivot":
-                    # Cheap pre-pass: O(tile·n) bound vs O(tile·m) exact.
-                    # Compact emission skips it — its filter runs fused
-                    # in-kernel and the survivor count comes back in-band.
-                    cand_dev = _tile_candidates(
-                        pv, pw, vids, wids, delta=float(delta),
-                        delta_bound=delta_bound,
-                    )
-                    # spjoin-lint: allow[host-sync] -- the whole-tile skip decision IS a sync: O(tile*n) bound read back to elide the O(tile*m) kernel
-                    n_cand = int(np.asarray(cand_dev).sum())
-                    stats.n_pruned += n_valid - n_cand
-                    if n_cand == 0:
-                        # Every pair pruned: the exact kernel never runs.
-                        stats.n_tiles_pruned += 1
-                        continue
-                    if backend != "pallas":
-                        premask = cand_dev  # jnp path reuses the bound
-                stats.n_tiles += 1
-                stats.n_padded += cap_v * cap_w
-                stats.n_dispatched += n_valid
-                stats.bucket_shapes.add((cap_v, cap_w))
-                if batch_w:
-                    pending.append((xv, xw, vids, wids, wc, h))
-                    pending_area += cap_v * cap_w
-                    if pending_area >= _BATCH_FLUSH_AREA:
-                        # Cap resident mask memory; early flushes are safe
-                        # (the final sort+unique canonicalizes pair order).
-                        _flush_window_batch(
-                            pending, delta, metric, cross,
-                            stats, chunks, return_pairs,
+                    with tracing.span("verify.w_tiles"):
+                        w_tiles = _prep_w_tiles_sorted(
+                            w_idx, w_data_cell, w_cells_cell, w_coords_cell,
+                            lo, hi, config, need_pw=prune == "pivot",
                         )
-                        pending_area = 0
-                    continue
-                # "window" prunes entirely on the host (above); the tile
-                # itself runs the plain verify — no per-pair bound lanes.
-                tile_prune = prune if prune == "pivot" else "none"
-                tile_band = delta_bound if tile_prune == "pivot" else None
-                mode = "compact" if buffered else "mask"
-                cap_pairs = 0
-                if mode == "compact":
-                    cap_pairs = bucket_size(
-                        int(n_valid * min(emit_rate * EMIT_SLACK, 1.0)) + _EMIT_FLOOR,
-                        cap_v * cap_w,
-                    )
-                tile_counts = None
-                out = None
-                for attempt in range(_MAX_OVERFLOW_RETRIES + 2):
-                    if mode == "compact":
-                        out_dev = _tile_compact(
-                            xv, xw, vids, wids, wc, h,
-                            delta=float(delta), metric=metric, backend=backend,
-                            capacity=cap_pairs, cross=cross, pv=pv, pw=pw,
-                            prune=tile_prune, delta_bound=tile_band,
-                        )
-                    else:
-                        out_dev = _tile_verify(
-                            xv, xw, vids, wids, wc, h,
-                            delta=float(delta), metric=metric, backend=backend,
-                            cross=cross, pv=pv, pw=pw, prune=tile_prune,
-                            premask=premask, delta_bound=tile_band,
-                        )
-                    # spjoin-lint: allow[host-sync] -- tile result must land on host to become (i, j) pairs; ONE readback per dispatch, both emission paths
-                    out = np.asarray(out_dev)
-                    if mode != "compact":
-                        break
-                    tile_counts = (int(out[-1, 0]), int(out[-1, 1]))
-                    if tile_counts[0] <= cap_pairs:
-                        break
-                    # Overflow sentinel: count > capacity means the buffer
-                    # contents are unspecified, but count itself is the TRUE
-                    # total — the retry bucket is sized exactly in one step.
-                    # Bounded retries, then the mask path as last resort;
-                    # the emitted pair set is identical on every rung.
-                    stats.n_overflow_retries += 1
-                    if attempt >= _MAX_OVERFLOW_RETRIES:
-                        mode = "mask"
-                    else:
-                        cap_pairs = bucket_size(
-                            max(tile_counts[0], 2 * cap_pairs), cap_v * cap_w
-                        )
-                if mode == "compact":
-                    count, n_cand = tile_counts
-                    if prune == "pivot":
-                        stats.n_pruned += n_valid - n_cand
-                    # Grow the prior from observed hit rates so one hot tile
-                    # does not turn into a retry per tile downstream.
-                    emit_rate = max(emit_rate, count / max(n_valid, 1))
-                    stats.n_hits += count
-                    if return_pairs and count:
-                        chunks.append(out[:count].astype(np.int64))
                 else:
-                    if tile_counts is not None and prune == "pivot":
-                        # Overflow fallback: the mask path ran, but the last
-                        # compact dispatch already reported the survivor
-                        # count — pruning telemetry stays emission-invariant.
-                        stats.n_pruned += n_valid - tile_counts[1]
-                    mask = out
-                    if not mask.any():
-                        continue
-                    vi, wi = np.nonzero(mask)
-                    stats.n_hits += vi.size
-                    if return_pairs:
-                        chunks.append(np.stack([vt[vi], wt[wi]], axis=1))
+                    xv, vids = _pad_gather(data_np, vt, cap_v)
+                for wt, cap_w, xw, wids, wc, pw, w_box in w_tiles:
+                    n_valid = int(vt.size) * int(wt.size)
+                    if v_box is not None and w_box is not None:
+                        # Bounding-box tile skip: interval arithmetic on the
+                        # mapped coordinates. The gap between the V and W boxes
+                        # lower-bounds every pair's L∞ bound, so a gap beyond
+                        # delta_bound means the whole tile is provable non-hits
+                        # — skipped before any dispatch, on every coordinate
+                        # (the window above only exploits the sort coordinate).
+                        gap = np.maximum(
+                            w_box[0] - v_box[1], v_box[0] - w_box[1]
+                        ).max()
+                        if gap > delta_bound:
+                            stats.n_pruned += n_valid
+                            stats.n_tiles_pruned += 1
+                            continue
+                    tile = tracing.span(
+                        "verify.tile", cap_v=cap_v, cap_w=cap_w, n_valid=n_valid
+                    )
+                    with tile:
+                        premask = None
+                        if emit == "mask" and prune == "pivot":
+                            # Cheap pre-pass: O(tile·n) bound vs O(tile·m) exact.
+                            # Compact emission skips it — its filter runs fused
+                            # in-kernel and the survivor count comes back in-band.
+                            with tracing.span("verify.prepass"):
+                                cand_dev = _tile_candidates(
+                                    pv, pw, vids, wids, delta=float(delta),
+                                    delta_bound=delta_bound,
+                                )
+                                # spjoin-lint: allow[host-sync] -- the whole-tile skip decision IS a sync: O(tile*n) bound read back to elide the O(tile*m) kernel
+                                n_cand = int(np.asarray(cand_dev).sum())
+                            stats.n_pruned += n_valid - n_cand
+                            if n_cand == 0:
+                                # Every pair pruned: the exact kernel never runs.
+                                stats.n_tiles_pruned += 1
+                                tile.add(n_cand=0, n_hits=0, retries=0)
+                                continue
+                            tile.add(n_cand=n_cand)
+                            if backend != "pallas":
+                                premask = cand_dev  # jnp path reuses the bound
+                        stats.n_tiles += 1
+                        stats.n_padded += cap_v * cap_w
+                        stats.n_dispatched += n_valid
+                        stats.bucket_shapes.add((cap_v, cap_w))
+                        if batch_w:
+                            pending.append((xv, xw, vids, wids, wc, h))
+                            pending_area += cap_v * cap_w
+                            if pending_area >= _BATCH_FLUSH_AREA:
+                                # Cap resident mask memory; early flushes are safe
+                                # (the final sort+unique canonicalizes pair order).
+                                _flush_window_batch(
+                                    pending, delta, metric, cross,
+                                    stats, chunks, return_pairs,
+                                )
+                                pending_area = 0
+                            continue
+                        # "window" prunes entirely on the host (above); the tile
+                        # itself runs the plain verify — no per-pair bound lanes.
+                        tile_prune = prune if prune == "pivot" else "none"
+                        tile_band = delta_bound if tile_prune == "pivot" else None
+                        mode = "compact" if buffered else "mask"
+                        cap_pairs = 0
+                        if mode == "compact":
+                            cap_pairs = bucket_size(
+                                int(n_valid * min(emit_rate * EMIT_SLACK, 1.0)) + _EMIT_FLOOR,
+                                cap_v * cap_w,
+                            )
+                        tile_counts = None
+                        out = None
+                        retries = 0
+                        for attempt in range(_MAX_OVERFLOW_RETRIES + 2):
+                            with tracing.span("verify.dispatch"):
+                                if mode == "compact":
+                                    out_dev = _tile_compact(
+                                        xv, xw, vids, wids, wc, h,
+                                        delta=float(delta), metric=metric, backend=backend,
+                                        capacity=cap_pairs, cross=cross, pv=pv, pw=pw,
+                                        prune=tile_prune, delta_bound=tile_band,
+                                    )
+                                else:
+                                    out_dev = _tile_verify(
+                                        xv, xw, vids, wids, wc, h,
+                                        delta=float(delta), metric=metric, backend=backend,
+                                        cross=cross, pv=pv, pw=pw, prune=tile_prune,
+                                        premask=premask, delta_bound=tile_band,
+                                    )
+                            with tracing.span("verify.readback"):
+                                # spjoin-lint: allow[host-sync] -- tile result must land on host to become (i, j) pairs; ONE readback per dispatch, both emission paths
+                                out = np.asarray(out_dev)
+                            if mode != "compact":
+                                break
+                            tile_counts = (int(out[-1, 0]), int(out[-1, 1]))
+                            if tile_counts[0] <= cap_pairs:
+                                break
+                            # Overflow sentinel: count > capacity means the buffer
+                            # contents are unspecified, but count itself is the TRUE
+                            # total — the retry bucket is sized exactly in one step.
+                            # Bounded retries, then the mask path as last resort;
+                            # the emitted pair set is identical on every rung.
+                            stats.n_overflow_retries += 1
+                            retries += 1
+                            if attempt >= _MAX_OVERFLOW_RETRIES:
+                                mode = "mask"
+                            else:
+                                cap_pairs = bucket_size(
+                                    max(tile_counts[0], 2 * cap_pairs), cap_v * cap_w
+                                )
+                        if tile_counts is not None:
+                            # The compact path's survivor count comes back in-band.
+                            tile.add(n_cand=tile_counts[1])
+                        with tracing.span("verify.emit"):
+                            if mode == "compact":
+                                count, n_cand = tile_counts
+                                if prune == "pivot":
+                                    stats.n_pruned += n_valid - n_cand
+                                # Grow the prior from observed hit rates so one hot tile
+                                # does not turn into a retry per tile downstream.
+                                emit_rate = max(emit_rate, count / max(n_valid, 1))
+                                n_hits = count
+                                if return_pairs and count:
+                                    chunks.append(out[:count].astype(np.int64))
+                            else:
+                                if tile_counts is not None and prune == "pivot":
+                                    # Overflow fallback: the mask path ran, but the last
+                                    # compact dispatch already reported the survivor
+                                    # count — pruning telemetry stays emission-invariant.
+                                    stats.n_pruned += n_valid - tile_counts[1]
+                                mask = out
+                                n_hits = 0
+                                if mask.any():
+                                    vi, wi = np.nonzero(mask)
+                                    n_hits = vi.size
+                                    if return_pairs:
+                                        chunks.append(np.stack([vt[vi], wt[wi]], axis=1))
+                            stats.n_hits += n_hits
+                        tile.add(n_hits=n_hits, retries=retries)
 
     if pending:
         _flush_window_batch(
             pending, delta, metric, cross, stats, chunks, return_pairs
         )
-    if chunks:
-        # Each pair is emitted once (min-cell rule / unique kernel cell);
-        # sort+unique is kept as a cheap invariant matching the seed
-        # executor. Cross pairs index different sets, so no column sort.
-        pairs = np.concatenate(chunks)
-        if not cross:
-            pairs = np.sort(pairs, axis=1)
-        pairs = np.unique(pairs, axis=0)
-    else:
-        pairs = np.zeros((0, 2), np.int64)
-    return pairs.astype(np.int64), stats
+    with tracing.span("verify.finalize"):
+        if chunks:
+            # Each pair is emitted once (min-cell rule / unique kernel cell);
+            # sort+unique is kept as a cheap invariant matching the seed
+            # executor. Cross pairs index different sets, so no column sort.
+            pairs = np.concatenate(chunks)
+            if not cross:
+                pairs = np.sort(pairs, axis=1)
+            pairs = np.unique(pairs, axis=0)
+        else:
+            pairs = np.zeros((0, 2), np.int64)
+        pairs = pairs.astype(np.int64)
+    return pairs, stats
 
 
 def verify_resident(

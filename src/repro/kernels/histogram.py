@@ -77,5 +77,6 @@ def histogram_blocked(
         out_specs=pl.BlockSpec((t, bmm), lambda j, i: (0, j)),
         out_shape=jax.ShapeDtypeStruct((t, mp), jnp.float32),
         interpret=interpret,
+        name="histogram_blocked",
     )(u, weights)
     return out[:, :m].T

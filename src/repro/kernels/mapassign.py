@@ -185,5 +185,6 @@ def map_assign_blocked(
             jax.ShapeDtypeStruct((n, pp // WORD), jnp.int32),
         ],
         interpret=interpret,
+        name="map_assign_blocked",
     )(x, anchors, kernel_lo, kernel_hi, whole_lo, whole_hi)
     return xm, cells, jax.lax.bitcast_convert_type(bits, jnp.uint32)

@@ -171,6 +171,7 @@ def pairdist_blocked(
         out_shape=jax.ShapeDtypeStruct((a, b), out_dtype),
         scratch_shapes=[pltpu.VMEM((bv, bw), jnp.float32)],
         interpret=interpret,
+        name="pairdist_blocked",
     )(x, y)
 
 
@@ -284,4 +285,5 @@ def pairdist_filtered_blocked(
             pltpu.VMEM((bv, bw), jnp.float32),
         ],
         interpret=interpret,
+        name="pairdist_filtered_blocked",
     )(x, y, px, py)

@@ -11,6 +11,8 @@ The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and test workers all import
 this file. Keep every such compile in this one file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -99,3 +101,57 @@ def test_verify_compact(spec, metric, prune):
         ),
         spec((a, 128)), spec((b, 128)), *ids, *coords,
     )
+
+
+# The names a profile shows for the kernels and the serve stage: the
+# benchmark reads device time by them (``bench/metrics/``).
+KERNEL_NAMES = {
+    "pairdist_filtered_blocked": (
+        lambda x, y, px, py: pairdist_filtered_blocked(
+            x, y, px, py, metric="l2", delta=1.0, delta_bound=1.1, interpret=False),
+        [(1024, 128), (4096, 128), (1024, 16), (4096, 16)],
+    ),
+    "pairdist_blocked": (
+        lambda x, y: pairdist_blocked(x, y, metric="l2", interpret=False),
+        [(1024, 128), (4096, 128)],
+    ),
+    "map_assign_blocked": (
+        lambda x, a, *b: map_assign_blocked(x, a, *b, metric="l2", bp=64, interpret=False),
+        [(4096, 128), (8, 128)] + [(64, 8)] * 4,
+    ),
+    "histogram_blocked": (
+        lambda u, w: histogram_blocked(u, w, t=8, interpret=False),
+        [(65536, 128), (65536, 1)],
+    ),
+}
+
+
+@pytest.mark.parametrize("name", sorted(KERNEL_NAMES))
+def test_kernel_keeps_its_name(spec, name):
+    fn, shapes = KERNEL_NAMES[name]
+    hlo = jax.jit(fn).lower(*(spec(s) for s in shapes)).compile().as_text()
+    assert re.search(rf"%{name}\.\d+ = [^\n]* custom-call\(", hlo), name
+
+
+def test_serve_stage_program_name(topo):
+    import dataclasses
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core import index as index_lib
+    from repro.core import spjoin
+
+    rng = np.random.default_rng(0)
+    r = (rng.random((300, 16)) > 0.5).astype(np.float32)
+    cfg = spjoin.JoinConfig(delta=2.0, metric="l2", k=64, p=8, n_dims=3)
+    didx = index_lib.build_index(r, cfg).to_distributed(jax.make_mesh((1,), ("data",)))
+    mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+    on_chip = dataclasses.replace(didx, mesh=mesh, backend="pallas", _stages={})
+    stage = on_chip._stage(2.0, 256, 2.5)
+    shard = NamedSharding(mesh, P("data"))
+    args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=shard) for a in (didx.fv, didx.fv_ids)]
+    args += [jax.ShapeDtypeStruct(s, d, sharding=shard)
+             for s, d in (((256, 16), jnp.float32), ((256,), jnp.float32), ((256,), jnp.int32))]
+    text = stage.lower(*args).as_text()
+    assert re.search(r"module @jit_per_shard\b", text)
