@@ -1133,6 +1133,7 @@ def make_stage_serve(
     pl: placement_lib.PlacementPlan,
     *,
     cap_w: int,
+    pair_cap: int,
     backend: str,
     prune: str,
     delta_bound: float | None = None,
@@ -1149,6 +1150,13 @@ def make_stage_serve(
     ``axis``, then per-local-slot ``verify_tile`` in R×S mode against the
     pinned V slots. No sampling, no partitioning, zero V-side bytes on the
     wire per batch.
+
+    The device's hit masks never leave it: they are compacted in the same
+    program (``kref.select_hits``) into a static (``pair_cap``, 2) int32
+    buffer of global (R id, query id) pairs in row-major (slot, v, w)
+    order, padded with -1, beside the TRUE hit count. A count above
+    ``pair_cap`` is the overflow sentinel (the buffer is then unspecified,
+    the count exact); ``DistIndex`` reruns the batch at a capacity it fits.
 
     The routing tables, W dispatch and shuffle closures are the exact ones
     ``make_stage_verify`` compiles with (module-level factories), so serving
@@ -1195,10 +1203,15 @@ def make_stage_serve(
             return mask, verify_lib.pair_validity(vids, wids).sum()
 
         masks, n_verified = jax.vmap(verify_slot)(fv, fvi, fw, fwi, fwo, local_cells)
+        cap_v = masks.shape[1]
+        rows, w, count = kref.select_hits(masks.reshape(-1, masks.shape[2]), pair_cap)
+        slot, v = rows // cap_v, rows % cap_v
+        pairs = jnp.stack([fvi[slot, v], fwi[slot, w]], axis=1)
+        fits = jnp.arange(pair_cap) < count
         return {
-            "masks": masks,  # (spd, cap_v, M*cap_w)
-            "w_ids": fwi,
-            "hits": masks.sum().astype(jnp.float32)[None],
+            "pairs": jnp.where(fits[:, None], pairs, -1),  # (pair_cap, 2)
+            "count": count[None],  # TRUE hits, > pair_cap on overflow
+            "hits": count.astype(jnp.float32)[None],
             "verified": n_verified.sum().astype(jnp.float32)[None],
             "overflow": overflow.astype(jnp.float32)[None],
         }
@@ -1208,7 +1221,7 @@ def make_stage_serve(
         mesh=mesh,
         in_specs=(P(axis),) * 5,
         out_specs={
-            "masks": P(axis), "w_ids": P(axis), "hits": P(axis),
+            "pairs": P(axis), "count": P(axis), "hits": P(axis),
             "verified": P(axis), "overflow": P(axis),
         },
         check_vma=False,
@@ -1241,9 +1254,12 @@ class DistIndex:
     fv: Array  # (n_slots, cap_v, m[+n]) pinned V payload, dispatch order,
     #   sharded over ``axis`` on dim 0
     fv_ids: Array  # (n_slots, cap_v) int32 global R ids, same layout
-    _fv_ids_host: np.ndarray  # host copy for pair extraction
     _x_abs: float  # max |payload| of the indexed set (prune-band input)
     _stages: dict = dataclasses.field(default_factory=dict, repr=False)
+    # Per-device pair capacity of the serve stage: a power of two that only
+    # grows, to the first one at least twice a batch's count that overflowed
+    # it, so repeat batches reuse the compiled stage.
+    _pair_cap: int = 1024
 
     @property
     def n_devices(self) -> int:
@@ -1309,12 +1325,11 @@ class DistIndex:
             cap_v=cap_v,
             fv=jax.device_put(jnp.asarray(buf_d), sharding),
             fv_ids=jax.device_put(jnp.asarray(ids_d), sharding),
-            _fv_ids_host=ids_d,
             _x_abs=float(np.abs(payload).max(initial=0.0)),
         )
 
-    def _stage(self, delta: float, cap_w: int, delta_bound: float | None):
-        key = (float(delta), int(cap_w), delta_bound)
+    def _stage(self, delta: float, cap_w: int, delta_bound: float | None, pair_cap: int):
+        key = (float(delta), int(cap_w), delta_bound, int(pair_cap))
         fn = self._stages.get(key)
         if fn is None:
             idx = self.index
@@ -1331,7 +1346,7 @@ class DistIndex:
             )
             fn = make_stage_serve(
                 self.mesh, self.axis, qplan, self.pl,
-                cap_w=cap_w, backend=self.backend, prune=self.prune,
+                cap_w=cap_w, pair_cap=pair_cap, backend=self.backend, prune=self.prune,
                 delta_bound=delta_bound, map_fused=idx.map_fused,
             )
             self._stages[key] = fn
@@ -1370,36 +1385,57 @@ class DistIndex:
                 cap_w = 1 << max(exact - 1, 1).bit_length()  # next pow2, ≥ 2
                 route.add(n_routed=int(w_cnt.sum()), cap_w=cap_w)
 
-            with tracing.span("serve.stage") as stage:
-                delta_bound = None
-                if self.prune == "pivot":
-                    # Scale-aware fp band; the query magnitude is quantized up
-                    # to a power of two so the (static) band doesn't recompile
-                    # per batch.
-                    q_abs = float(np.abs(q_np).max(initial=0.0))
-                    q_pow = float(2.0 ** np.ceil(np.log2(max(q_abs, 1e-9))))
-                    x_abs = max(self._x_abs, q_pow)
-                    delta_bound = kref.prune_delta(
-                        delta, idx.metric, x_abs, int(idx.data.shape[1])
-                    )
-                n_stages = len(self._stages)
-                fn = self._stage(delta, cap_w, delta_bound)
-                stage.add(compiled=int(len(self._stages) > n_stages))
-                out = fn(self.fv, self.fv_ids, q_arr, valid, ids)
-
-            with tracing.span("serve.readback") as readback:
-                assert int(np.asarray(out["overflow"]).sum()) == 0, "serve W overflow"
-                masks = np.asarray(out["masks"])  # (n_slots, cap_v, M*cap_w)
-                w_ids = np.asarray(out["w_ids"]).reshape(masks.shape[0], -1)
-                readback.add(mask_elems=int(masks.size))
+            batch = (q_np, q_arr, valid, ids)
+            counts, buf, pair_cap = self._run_stage(batch, delta, cap_w, 0)
+            top = int(counts.max())
+            if top > pair_cap:
+                # The count is exact: one rerun at the next power of two
+                # ≥ 2·count always fits.
+                self._pair_cap = max(self._pair_cap, 1 << (2 * top - 1).bit_length())
+                counts, buf, pair_cap = self._run_stage(batch, delta, cap_w, 1)
 
             with tracing.span("serve.unpack") as unpack:
-                slot, vi, wi = np.nonzero(masks)
-                gi = self._fv_ids_host[slot, vi]
-                gj = w_ids[slot, wi]
-                pairs = np.unique(np.stack([gi, gj], axis=1), axis=0).astype(np.int64)
-                unpack.add(n_hits=int(slot.size), n_pairs=int(pairs.shape[0]))
+                buf = buf.reshape(M, pair_cap, 2)
+                pr = np.concatenate([b[:c] for b, c in zip(buf, counts)]).astype(np.int64)
+                # Sorted unique (i, j) rows, through one int64 key a pair: a
+                # 1-D unique, where np.unique(axis=0) sorts rows as bytes.
+                n_q = int(q_arr.shape[0])
+                key = np.unique(pr[:, 0] * n_q + pr[:, 1])
+                pairs = np.stack([key // n_q, key % n_q], axis=1)
+                unpack.add(n_hits=int(counts.sum()), n_pairs=int(pairs.shape[0]))
         return pairs
+
+    def _run_stage(
+        self, batch: tuple, delta: float, cap_w: int, retries: int
+    ) -> tuple[np.ndarray, np.ndarray, int]:
+        """Dispatch the serve stage at the current pair capacity (clamped to
+        a device's mask elements) and read back, in one copy, its overflow
+        flags, per-device hit counts and pair buffers."""
+        q_np, q_arr, valid, ids = batch
+        elems = self.pl.n_slots * self.cap_v * cap_w  # a device's mask elements
+        pair_cap = min(self._pair_cap, elems)
+        with tracing.span("serve.stage") as stage:
+            delta_bound = None
+            if self.prune == "pivot":
+                # Scale-aware fp band; the query magnitude is quantized up to
+                # a power of two so the (static) band doesn't recompile per
+                # batch.
+                q_abs = float(np.abs(q_np).max(initial=0.0))
+                q_pow = float(2.0 ** np.ceil(np.log2(max(q_abs, 1e-9))))
+                x_abs = max(self._x_abs, q_pow)
+                delta_bound = kref.prune_delta(
+                    delta, self.index.metric, x_abs, int(self.index.data.shape[1])
+                )
+            n_stages = len(self._stages)
+            fn = self._stage(delta, cap_w, delta_bound, pair_cap)
+            stage.add(compiled=int(len(self._stages) > n_stages), pair_retries=retries)
+            out = fn(self.fv, self.fv_ids, q_arr, valid, ids)
+
+        with tracing.span("serve.readback") as readback:
+            overflow, counts, buf = jax.device_get((out["overflow"], out["count"], out["pairs"]))
+            assert int(overflow.sum()) == 0, "serve W overflow"
+            readback.add(mask_elems=self.n_devices * elems, pair_cap=pair_cap)
+        return counts, buf, pair_cap
 
     def _repin(self) -> None:
         """Re-lay the host index out on the mesh after an absorb (or a
@@ -1414,7 +1450,6 @@ class DistIndex:
         self.cap_v = fresh.cap_v
         self.fv = fresh.fv
         self.fv_ids = fresh.fv_ids
-        self._fv_ids_host = fresh._fv_ids_host
         self._x_abs = fresh._x_abs
         self._stages.clear()
 

@@ -226,6 +226,46 @@ def compact_mask(
     return jnp.stack([pv, pw], axis=1), count
 
 
+def select_hits(mask: Array, capacity: int) -> tuple[Array, Array, Array]:
+    """Row and column of the first ``capacity`` hits of a 2-D mask.
+
+    Returns ``(rows, cols, count)``: ``rows`` and ``cols`` are (capacity,)
+    int32 in row-major (``np.nonzero``) order, meaningful for the first
+    ``min(count, capacity)`` entries and clamped into the mask elsewhere;
+    ``count`` is int32 and equals the TRUE number of hits (``count >
+    capacity`` is the overflow sentinel, as in :func:`compact_mask`).
+
+    A two-level rank, for masks too large for one flat prefix sum: the hits
+    of each chunk of 128 columns are counted, the chunk counts take a
+    :func:`prefix_sum`, a ``searchsorted`` finds the k-th hit's chunk, and
+    one 128-wide triangular matmul over the gathered chunks ranks it within
+    its chunk. Work past the chunk counts grows with ``capacity``, not with
+    the mask.
+    """
+    a, b = mask.shape
+    if a == 0 or b == 0:
+        zeros = jnp.zeros((capacity,), jnp.int32)
+        return zeros, zeros, jnp.zeros((), jnp.int32)
+    width = min(b, _SCAN_WIDTH)
+    per_row = -(-b // width)
+    if per_row * width != b:
+        mask = jnp.pad(mask, ((0, 0), (0, per_row * width - b)))
+    chunks = mask.reshape(-1, width)
+    counts = chunks.sum(axis=1, dtype=jnp.int32)
+    incl = prefix_sum(counts, width)
+    count = incl[-1]
+    k = jnp.arange(1, capacity + 1, dtype=jnp.int32)
+    chunk = jnp.minimum(jnp.searchsorted(incl, k, side="left"), chunks.shape[0] - 1)
+    rank = k - (incl[chunk] - counts[chunk])  # 1-based, within the chunk
+    upper = jnp.triu(jnp.ones((width, width), jnp.float32))
+    within = jnp.dot(
+        chunks[chunk].astype(jnp.float32), upper, precision=jax.lax.Precision.HIGHEST
+    )
+    col = (within < rank[:, None].astype(jnp.float32)).sum(axis=1, dtype=jnp.int32)
+    cols = jnp.minimum((chunk % per_row) * width + col, b - 1)
+    return (chunk // per_row).astype(jnp.int32), cols.astype(jnp.int32), count
+
+
 def verify_compact(
     x: Array,
     y: Array,

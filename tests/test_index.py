@@ -265,6 +265,61 @@ def test_dist_index_parity_1dev(rng):
     np.testing.assert_array_equal(didx.query_batch(q, 1.6), truth_wide)
 
 
+@pytest.mark.parametrize("case", ["overflow", "no_hits", "repeat"])
+def test_dist_index_compacted_pairs(case, rng, monkeypatch):
+    """The serve stage hands back compacted pairs, byte-identical to the host
+    index and the brute-force oracle: when the first run overflows the pair
+    capacity (one rerun at a grown capacity), with no hit at all, and on a
+    repeated batch that reuses the compiled stage."""
+    from repro.core import distances, tracing
+
+    r, q = _dataset(rng, "l2", n=300, n_q=90)
+    if case == "no_hits":
+        q = q + 100.0  # far from every indexed row
+    idx = _build(r, "l2", 1.0)
+    didx = idx.to_distributed(jax.make_mesh((1,), ("data",)))
+    if case == "overflow":
+        didx._pair_cap = 1
+    if case == "repeat":
+        didx.query_batch(q)
+    cap_before = didx._pair_cap
+
+    made: list = []
+
+    class Recorded(tracing.span):  # keeps its counters, traced or not
+        def __init__(self, name, **counts):
+            super().__init__(name, **counts)
+            self.name, self.counts = name, dict(counts)
+            made.append(self)
+
+        def add(self, **counts):
+            super().add(**counts)
+            self.counts.update(counts)
+
+    monkeypatch.setattr(tracing, "span", Recorded)
+    got = didx.query_batch(q)
+    oracle = np.argwhere(np.asarray(distances.brute_force_join(r, q, 1.0, "l2")))
+    assert got.dtype == np.int64
+    assert got.tobytes() == idx.query_batch(q).tobytes() == oracle.astype(np.int64).tobytes()
+
+    stages = [s.counts for s in made if s.name == "serve.stage"]
+    readbacks = [s.counts for s in made if s.name == "serve.readback"]
+    (unpack,) = [s.counts for s in made if s.name == "serve.unpack"]
+    assert unpack["n_pairs"] == got.shape[0] and unpack["n_hits"] >= got.shape[0]
+    assert readbacks[-1]["pair_cap"] >= unpack["n_hits"]
+    if case == "overflow":
+        assert got.shape[0] > 1
+        assert sum(s["pair_retries"] for s in stages) >= 1 and len(stages) == 2
+        assert didx._pair_cap > cap_before and readbacks[-1]["pair_cap"] > 1
+    else:
+        assert [s["pair_retries"] for s in stages] == [0]
+        assert didx._pair_cap == cap_before
+    if case == "no_hits":
+        assert got.shape == (0, 2) and unpack["n_hits"] == 0
+    if case == "repeat":
+        assert stages[0]["compiled"] == 0
+
+
 def test_dist_index_rejects_kernel_less_metrics(rng):
     r, _ = _dataset(rng, "angular")
     idx = _build(r, "angular", 0.15)

@@ -117,6 +117,37 @@ def test_prefix_sum_matches_cumsum(n, peak):
     assert np.array_equal(got, np.cumsum(counts, dtype=np.int32))
 
 
+@pytest.mark.parametrize(
+    "a,b,density,capacity",
+    [
+        (1, 1, 1.0, 1),  # one element
+        (37, 5, 0.3, 64),  # narrower than a chunk
+        (9, 256, 0.05, 200),  # whole chunks, as a one-chip serve batch's rows
+        (6, 300, 0.1, 256),  # a ragged last chunk per row
+        (300, 128, 0.02, 1024),  # many chunks, a multi-level prefix sum
+        (17, 200, 0.5, 40),  # overflow: more hits than capacity
+        (8, 512, 0.0, 16),  # no hit
+    ],
+)
+def test_select_hits_matches_nonzero(a, b, density, capacity):
+    """The two-level rank returns the first ``capacity`` hits in
+    ``np.nonzero`` order and the exact count, for any width and density."""
+    mask = np.random.default_rng(a * b).random((a, b)) < density
+    rows, cols, cnt = kref.select_hits(jnp.asarray(mask), capacity)
+    rows, cols, cnt = np.asarray(rows), np.asarray(cols), int(cnt)
+    vi, wi = np.nonzero(mask)
+    assert cnt == vi.size
+    n = min(cnt, capacity)
+    assert np.array_equal(rows[:n], vi[:n]) and np.array_equal(cols[:n], wi[:n])
+    assert rows.shape == cols.shape == (capacity,)
+    assert ((0 <= rows) & (rows < a) & (0 <= cols) & (cols < b)).all()
+
+
+def test_select_hits_empty_mask():
+    rows, cols, cnt = kref.select_hits(jnp.zeros((0, 4), bool), 3)
+    assert int(cnt) == 0 and np.asarray(rows).shape == np.asarray(cols).shape == (3,)
+
+
 def test_compaction_edge_tiles():
     """Edge tiles: empty, all-pruned, exactly-full, and a single hit at flat
     index 0 / at the last flat cell landing in buffer slot 0 / capacity-1."""

@@ -133,7 +133,9 @@ def test_kernel_keeps_its_name(spec, name):
     assert re.search(rf"%{name}\.\d+ = [^\n]* custom-call\(", hlo), name
 
 
-def test_serve_stage_program_name(topo):
+def test_serve_stage_program_name(topo, monkeypatch):
+    """The compacting serve stage keeps its program name, and compiles for
+    the described v5e with its Pallas kernels (not their interpreter)."""
     import dataclasses
 
     import numpy as np
@@ -141,6 +143,7 @@ def test_serve_stage_program_name(topo):
 
     from repro.core import index as index_lib
     from repro.core import spjoin
+    from repro.kernels import ops as kops
 
     rng = np.random.default_rng(0)
     r = (rng.random((300, 16)) > 0.5).astype(np.float32)
@@ -148,10 +151,12 @@ def test_serve_stage_program_name(topo):
     didx = index_lib.build_index(r, cfg).to_distributed(jax.make_mesh((1,), ("data",)))
     mesh = Mesh(np.array(topo.devices[:1]), ("data",))
     on_chip = dataclasses.replace(didx, mesh=mesh, backend="pallas", _stages={})
-    stage = on_chip._stage(2.0, 256, 2.5)
+    stage = on_chip._stage(2.0, 256, 2.5, on_chip._pair_cap)
     shard = NamedSharding(mesh, P("data"))
     args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=shard) for a in (didx.fv, didx.fv_ids)]
     args += [jax.ShapeDtypeStruct(s, d, sharding=shard)
              for s, d in (((256, 16), jnp.float32), ((256,), jnp.float32), ((256,), jnp.int32))]
-    text = stage.lower(*args).as_text()
-    assert re.search(r"module @jit_per_shard\b", text)
+    monkeypatch.setattr(kops, "_interpret", lambda: False)
+    lowered = stage.lower(*args)
+    assert re.search(r"module @jit_per_shard\b", lowered.as_text())
+    assert "tpu_custom_call" in lowered.compile().as_text()

@@ -125,12 +125,35 @@ def test_serve_batch_span_tree_and_counters(rng, tmp_path):
     for i, pairs, compiled in zip(roots, (first, second), (1, 0)):
         assert spans.spans[i].counts["n_queries"] == 90
         kids = {s.name: s for s in spans.spans if s.parent == i}
-        assert list(kids) == SERVE_CHILDREN
+        assert [s.name for s in spans.spans if s.parent == i] == SERVE_CHILDREN
         assert kids["serve.route"].counts["cap_w"] >= 2
         assert kids["serve.route"].counts["n_routed"] >= 90
         assert kids["serve.stage"].counts["compiled"] == compiled
+        assert kids["serve.stage"].counts["pair_retries"] == 0
         elems = kids["serve.readback"].counts["mask_elems"]
         assert elems == didx.pl.n_slots * didx.cap_v * kids["serve.route"].counts["cap_w"]
         assert kids["serve.unpack"].counts["n_pairs"] == pairs.shape[0]
         assert kids["serve.unpack"].counts["n_hits"] >= pairs.shape[0] > 0
+        assert kids["serve.readback"].counts["pair_cap"] >= kids["serve.unpack"].counts["n_hits"]
     np.testing.assert_array_equal(first, second)
+
+
+def test_serve_batch_rerun_on_pair_overflow(rng, tmp_path):
+    """A batch with more hits than the pair capacity runs the stage and its
+    readback again at a grown capacity, and says so in ``pair_retries``."""
+    r = rng.normal(size=(300, 5)).astype(np.float32)
+    q = rng.normal(size=(90, 5)).astype(np.float32)
+    cfg = spjoin.JoinConfig(delta=1.0, metric="l2", k=64, p=8, n_dims=3)
+    didx = index_lib.build_index(r, cfg).to_distributed(jax.make_mesh((1,), ("data",)))
+    didx._pair_cap = 2
+    pairs, spans = _traced(tmp_path, lambda: didx.query_batch(q))
+    (root,) = [i for i, s in enumerate(spans.spans) if s.parent == -1]
+    kids = [s for s in spans.spans if s.parent == root]
+    assert [s.name for s in kids] == [
+        "serve.put", "serve.route", "serve.stage", "serve.readback", "serve.stage",
+        "serve.readback", "serve.unpack"]
+    assert [s.counts["pair_retries"] for s in kids if s.name == "serve.stage"] == [0, 1]
+    caps = [s.counts["pair_cap"] for s in kids if s.name == "serve.readback"]
+    n_hits = kids[-1].counts["n_hits"]
+    assert caps[0] == 2 < n_hits <= caps[1] == didx._pair_cap
+    assert kids[-1].counts["n_pairs"] == pairs.shape[0]

@@ -365,8 +365,8 @@ def repo_entries() -> list[dict]:
 
     pl = placement_lib.plan_placement(np.zeros(p, np.float64), 1, strategy="contiguous")
     serve_fn = dist.make_stage_serve(
-        mesh, "data", plan, pl, cap_w=8, backend="numpy", prune="pivot",
-        delta_bound=1.01,
+        mesh, "data", plan, pl, cap_w=8, pair_cap=16, backend="numpy",
+        prune="pivot", delta_bound=1.01,
     )
     fv = jnp.zeros((pl.n_slots, 8, m + plan.anchors.shape[0]), f32)
     fvi = jnp.zeros((pl.n_slots, 8), jnp.int32)
