@@ -18,7 +18,9 @@ calls, cold (compile included) and warm:
                ``query_batch`` of 4,096 held-out queries, several times
 
 ``--four-chips`` runs only ``distributed_join`` over the same 2^19 rows on
-a 4-chip mesh, and prints the rows each device holds after the shuffle.
+a 4-chip mesh, and prints the rows each device holds after the shuffle. Its
+warm run reuses the cold run's compiled stages. The measured form of the
+four-chip join is the benchmark cell ``hamming256-dist4-join`` (PERF.md).
 
 Every phase's pairs must be byte-identical to the blocked device oracle
 (``spjoin.brute_force_pairs`` / ``index.brute_force_query``), and its

@@ -74,6 +74,54 @@ Array = jnp.ndarray
 
 
 # ---------------------------------------------------------------------------
+# Compiled join stages, reused across joins
+# ---------------------------------------------------------------------------
+
+# The join's stages keyed by their static configuration: the mesh, the
+# metric, the placement's routing tables and the capacities. The plan's
+# anchors and boxes are arguments, so a second join of the same shapes
+# under the same static configuration reuses every program. Least recently
+# used first; the oldest stage goes when more than ``_STAGES_HELD`` are held.
+_STAGES: dict[tuple, Any] = {}
+_STAGES_HELD = 32
+
+
+# The compact stage's first pair capacity per slot, for a configuration
+# this process has not run yet. The survival estimate that sizes it is the
+# pivot bound's, an overestimate of the hits: where the bound prunes
+# nothing it reads the whole slot, and the compaction then costs as much as
+# the slot's mask. An overflow reruns the stage at a capacity sized from
+# the exact count, and that capacity is kept for the configuration's next
+# joins.
+_FIRST_PAIR_CAP = 1 << 16
+
+
+def _remember(key: tuple, value) -> None:
+    _STAGES.pop(key, None)
+    while len(_STAGES) >= _STAGES_HELD:
+        _STAGES.pop(next(iter(_STAGES)))
+    _STAGES[key] = value
+
+
+def _stage(key: tuple, build):
+    fn = _STAGES.get(key)
+    if fn is None:
+        fn = build()
+    _remember(key, fn)
+    return fn
+
+
+def clear_join_stages() -> int:
+    """Drop every join stage held for reuse, and with them their compiled
+    programs and the pair capacities that fitted earlier joins; returns
+    how many entries there were. The serving stages of a ``DistIndex`` are
+    its own and stay."""
+    n = len(_STAGES)
+    _STAGES.clear()
+    return n
+
+
+# ---------------------------------------------------------------------------
 # Stage 1: per-shard stats + gather (sampling phase stages 1-2)
 # ---------------------------------------------------------------------------
 
@@ -111,28 +159,32 @@ def make_stage_stats(
 ):
     """Build the jitted stats stage. Input: global (N, m) data sharded on
     ``axis`` plus an (N,) validity mask. Output (replicated): per-node packed
-    params (M, 2m+1), confidences (M,), counts (M,)."""
+    params (M, 2m+1), confidences (M,), counts (M,). Built once per static
+    configuration (``_stage``)."""
     backend = kops.resolve_backend(backend, use_kernel=use_kernel)
 
-    def per_shard(x: Array, valid: Array):
-        packed, confs = _fit_all_families(x, valid, t_cells, backend)
-        best = jnp.argmax(confs)
-        my_packet = packed[best]
-        my_conf = confs[best]
-        my_count = valid.astype(jnp.float32).sum()
-        packets = jax.lax.all_gather(my_packet, axis)  # (M, 2m+1)
-        conf_all = jax.lax.all_gather(my_conf, axis)  # (M,)
-        count_all = jax.lax.all_gather(my_count, axis)  # (M,)
-        return packets, conf_all, count_all
+    def build():
+        def per_shard(x: Array, valid: Array):
+            packed, confs = _fit_all_families(x, valid, t_cells, backend)
+            best = jnp.argmax(confs)
+            my_packet = packed[best]
+            my_conf = confs[best]
+            my_count = valid.astype(jnp.float32).sum()
+            packets = jax.lax.all_gather(my_packet, axis)  # (M, 2m+1)
+            conf_all = jax.lax.all_gather(my_conf, axis)  # (M,)
+            count_all = jax.lax.all_gather(my_count, axis)  # (M,)
+            return packets, conf_all, count_all
 
-    shmap = jax.shard_map(
-        per_shard,
-        mesh=mesh,
-        in_specs=(P(axis), P(axis)),
-        out_specs=(P(), P(), P()),
-        check_vma=False,
-    )
-    return jax.jit(shmap)
+        shmap = jax.shard_map(
+            per_shard,
+            mesh=mesh,
+            in_specs=(P(axis), P(axis)),
+            out_specs=(P(), P(), P()),
+            check_vma=False,
+        )
+        return jax.jit(shmap)
+
+    return _stage(("stats", mesh, axis, t_cells, backend), build)
 
 
 # ---------------------------------------------------------------------------
@@ -231,6 +283,20 @@ def build_join_plan(
     )
 
 
+def _plan_arrays(plan: JoinPlan) -> tuple[Array, ...]:
+    """The plan's anchors and boxes, the arguments a join stage takes."""
+    return tuple(
+        jnp.asarray(a, jnp.float32)
+        for a in (plan.anchors, plan.kernel_lo, plan.kernel_hi, plan.whole_lo, plan.whole_hi)
+    )
+
+
+def _traced_plan(arrays: tuple[Array, ...], metric: str, delta: float | None, p: int) -> JoinPlan:
+    """The plan inside a stage, from its arguments and the static rest."""
+    anchors, klo, khi, wlo, whi = arrays
+    return JoinPlan(anchors, metric, klo, khi, wlo, whi, delta, p)
+
+
 def _map_assign(plan: JoinPlan, x: Array, valid: Array, backend: str, fused: bool = True):
     """Space-map a shard and compute kernel cell + whole membership.
 
@@ -286,30 +352,40 @@ def make_stage_counts(
 
     ``fused``: route the map pass through the single-pass
     ``kernels.ops.map_assign`` kernel (default) or the legacy two-broadcast
-    jnp path (the benchmark/parity control)."""
+    jnp path (the benchmark/parity control).
+
+    The plan's anchors and boxes are arguments of the compiled stage, so
+    the tightened recount and later joins of the same shapes reuse it."""
     big = jnp.float32(partition.BIG)
-    backend = kops.resolve_backend(backend, plan.metric, use_kernel)
+    metric, p = plan.metric, plan.p
+    backend = kops.resolve_backend(backend, metric, use_kernel)
 
-    def per_shard(x: Array, valid: Array):
-        cells, member, v, xm = _map_assign(plan, x, valid, backend, fused)
-        v_cnt = jnp.zeros((plan.p,), jnp.int32).at[cells].add(v.astype(jnp.int32))
-        w_cnt = member.sum(0).astype(jnp.int32)
-        safe_cells = jnp.where(v, cells, plan.p)  # invalid -> dropped
-        lo = jnp.full((plan.p + 1, xm.shape[1]), big).at[safe_cells].min(xm)[: plan.p]
-        hi = jnp.full((plan.p + 1, xm.shape[1]), -big).at[safe_cells].max(xm)[: plan.p]
-        return (
-            jax.lax.all_gather(v_cnt, axis),  # (M, p)
-            jax.lax.all_gather(w_cnt, axis),
-            jax.lax.all_gather(lo, axis),  # (M, p, n)
-            jax.lax.all_gather(hi, axis),
+    def build():
+        def per_shard(arrays, x: Array, valid: Array):
+            # The counting pass reads no δ: the boxes already hold it.
+            traced = _traced_plan(arrays, metric, None, p)
+            cells, member, v, xm = _map_assign(traced, x, valid, backend, fused)
+            v_cnt = jnp.zeros((p,), jnp.int32).at[cells].add(v.astype(jnp.int32))
+            w_cnt = member.sum(0).astype(jnp.int32)
+            safe_cells = jnp.where(v, cells, p)  # invalid -> dropped
+            lo = jnp.full((p + 1, xm.shape[1]), big).at[safe_cells].min(xm)[:p]
+            hi = jnp.full((p + 1, xm.shape[1]), -big).at[safe_cells].max(xm)[:p]
+            return (
+                jax.lax.all_gather(v_cnt, axis),  # (M, p)
+                jax.lax.all_gather(w_cnt, axis),
+                jax.lax.all_gather(lo, axis),  # (M, p, n)
+                jax.lax.all_gather(hi, axis),
+            )
+
+        shmap = jax.shard_map(
+            per_shard, mesh=mesh, in_specs=(P(), P(axis), P(axis)),
+            out_specs=(P(), P(), P(), P()),
+            check_vma=False,
         )
+        return jax.jit(shmap)
 
-    shmap = jax.shard_map(
-        per_shard, mesh=mesh, in_specs=(P(axis), P(axis)),
-        out_specs=(P(), P(), P(), P()),
-        check_vma=False,
-    )
-    return jax.jit(shmap)
+    fn = _stage(("counts", mesh, axis, metric, p, backend, fused), build)
+    return functools.partial(fn, _plan_arrays(plan))
 
 
 # ---------------------------------------------------------------------------
@@ -385,6 +461,14 @@ def _routing_tables(pl: placement_lib.PlacementPlan) -> _RoutingTables:
             np.where(cell_of_disp_np >= 0, cell_of_disp_np, p), jnp.int32
         ),
         cell_id_of_disp=jnp.asarray(cell_of_disp_np, jnp.int32),
+    )
+
+
+def _placement_key(pl: placement_lib.PlacementPlan) -> tuple:
+    """What of a placement plan a stage compiles in: its routing tables."""
+    return (pl.p, pl.n_slots) + tuple(
+        tuple(np.asarray(a).tolist())
+        for a in (pl.cell_first_slot, pl.cell_n_slabs, pl.dispatch_of_slot, pl.cell_of_dispatch)
     )
 
 
@@ -538,160 +622,174 @@ def make_stage_verify(
             np.zeros(p, np.float64), M, strategy="contiguous"
         )
     assert pl.p == p, f"placement planned for p={pl.p}, stage has p={p}"
-    rt = _routing_tables(pl)
-    n_slots = rt.n_slots
+    n_slots = pl.n_slots
     assert n_slots % M == 0, f"n_slots={n_slots} must be a multiple of {axis}={M}"
     spd = n_slots // M  # dispatch slots per device
     cap_v, cap_w = vcfg.cap_v, vcfg.cap_w
     map_fused = vcfg.map_fused
-    backend = kops.resolve_backend(vcfg.backend, plan.metric, vcfg.use_kernel)
+    metric, delta = plan.metric, plan.delta
+    backend = kops.resolve_backend(vcfg.backend, metric, vcfg.use_kernel)
     if vcfg.prune == "window":
         # Host-streamed range pruning has no analogue inside a static
         # shard_map trace; the distributed stage filters per pair.
         raise ValueError('the distributed stage supports prune="none" | "pivot"')
-    prune = verify_lib.resolve_prune(vcfg.prune, plan.metric, True)
-    emit = verify_lib.resolve_emit(vcfg.emit, plan.metric) if vcfg.emit_pairs else "mask"
+    prune = verify_lib.resolve_prune(vcfg.prune, metric, True)
+    emit = verify_lib.resolve_emit(vcfg.emit, metric) if vcfg.emit_pairs else "mask"
     if emit == "compact" and vcfg.pair_cap < 1:
         raise ValueError('emit="compact" needs pair_cap >= 1 (a static out-shape)')
     n_dims = plan.anchors.shape[0]
     delta_bound = vcfg.delta_bound  # static — shared by mask + telemetry
 
-    # Static routing tables + dispatch/shuffle closures (identity permutation
-    # under contiguous placement) — shared with make_stage_serve.
-    cell_id_of_disp = rt.cell_id_of_disp
-    v_dispatch = _make_v_dispatch(rt, cap_v)
-    w_dispatch = _make_w_dispatch(rt, cap_w)
-    exchange, flat = _make_exchange(axis, M, spd)
+    def build():
+        # Static routing tables + dispatch/shuffle closures (identity permutation
+        # under contiguous placement) — shared with make_stage_serve.
+        rt = _routing_tables(pl)
+        cell_id_of_disp = rt.cell_id_of_disp
+        v_dispatch = _make_v_dispatch(rt, cap_v)
+        w_dispatch = _make_w_dispatch(rt, cap_w)
+        exchange, flat = _make_exchange(axis, M, spd)
 
-    def shuffle_and_verify(v_parts, w_parts, overflow):
-        """ONE all_to_all per side over the data axis, then per-local-slot
-        masked blocked verification."""
-        fv, fvi, fvo = (flat(exchange(b)) for b in v_parts)
-        fw, fwi, fwo = (flat(exchange(b)) for b in w_parts)
+        def shuffle_and_verify(v_parts, w_parts, overflow):
+            """ONE all_to_all per side over the data axis, then per-local-slot
+            masked blocked verification."""
+            fv, fvi, fvo = (flat(exchange(b)) for b in v_parts)
+            fw, fwi, fwo = (flat(exchange(b)) for b in w_parts)
 
-        my_dev = jax.lax.axis_index(axis)
-        # De-dup runs against the slot's ORIGINAL cell id (slabs share it),
-        # so placement can never change which pairs a cell emits.
-        local_cells = cell_id_of_disp[my_dev * spd + jnp.arange(spd)]
+            my_dev = jax.lax.axis_index(axis)
+            # De-dup runs against the slot's ORIGINAL cell id (slabs share it),
+            # so placement can never change which pairs a cell emits.
+            local_cells = cell_id_of_disp[my_dev * spd + jnp.arange(spd)]
 
-        # Distances, threshold, padding validity, the de-dup rule and the
-        # pivot filter all live in repro.core.verify — the same code path
-        # the reference executor streams through.
-        def verify_cell(vx, vids, vown, wx, wids, wown, cell_id):
-            pv = pw = None
-            if prune == "pivot":
-                # Mapped coords rode the payload's trailing n_dims columns.
-                vx, pv = vx[:, :-n_dims], vx[:, -n_dims:]
-                wx, pw = wx[:, :-n_dims], wx[:, -n_dims:]
-            mask = verify_lib.verify_tile(
-                vx, wx, vids, wids, wown, cell_id,
-                delta=plan.delta, metric=plan.metric, backend=backend,
-                cross=cross, pv=pv, pw=pw, prune=prune,
-                delta_bound=delta_bound,
-            )
-            n_verified = verify_lib.pair_validity(vids, wids).sum()
-            if prune == "pivot":
-                n_cand = verify_lib.candidate_mask(
-                    pv, pw, vids, wids, plan.delta, delta_bound
-                ).sum()
+            # Distances, threshold, padding validity, the de-dup rule and the
+            # pivot filter all live in repro.core.verify — the same code path
+            # the reference executor streams through.
+            def verify_cell(vx, vids, vown, wx, wids, wown, cell_id):
+                pv = pw = None
+                if prune == "pivot":
+                    # Mapped coords rode the payload's trailing n_dims columns.
+                    vx, pv = vx[:, :-n_dims], vx[:, -n_dims:]
+                    wx, pw = wx[:, :-n_dims], wx[:, -n_dims:]
+                mask = verify_lib.verify_tile(
+                    vx, wx, vids, wids, wown, cell_id,
+                    delta=delta, metric=metric, backend=backend,
+                    cross=cross, pv=pv, pw=pw, prune=prune,
+                    delta_bound=delta_bound,
+                )
+                n_verified = verify_lib.pair_validity(vids, wids).sum()
+                if prune == "pivot":
+                    n_cand = verify_lib.candidate_mask(
+                        pv, pw, vids, wids, delta, delta_bound
+                    ).sum()
+                else:
+                    n_cand = n_verified
+                return mask, n_verified, n_cand
+
+            slots = (fv, fvi, fvo, fw, fwi, fwo, local_cells)
+            if emit == "compact":
+                # One slot at a time: only that slot's (M·cap_v, M·cap_w) mask
+                # is ever live, and only its compacted pairs leave the loop —
+                # under vmap every slot's mask would sit in HBM at once.
+                # The masks are already validity- and de-dup-filtered
+                # (verify_tile -> ref.emit_mask), so compaction just gathers
+                # global ids.
+                def compact_slot(slot):
+                    mask, n_ver, n_cnd = verify_cell(*slot)
+                    pairs, count = kref.compact_mask(mask, slot[1], slot[4], vcfg.pair_cap)
+                    return pairs, count, n_ver, n_cnd
+
+                cpairs, ccounts, n_verified, n_cand = jax.lax.map(compact_slot, slots)
+                hit_count = ccounts.sum()
             else:
-                n_cand = n_verified
-            return mask, n_verified, n_cand
+                masks, n_verified, n_cand = jax.vmap(verify_cell)(*slots)
+                hit_count = masks.sum()
+            out = {
+                "hits": hit_count.astype(jnp.float32)[None],
+                "verified": n_verified.sum().astype(jnp.float32)[None],
+                "candidates": n_cand.sum().astype(jnp.float32)[None],
+                # Per DISPATCH SLOT (== per cell under contiguous placement); the
+                # host folds slabs back to cells and devices after the stage.
+                "per_cell_verified": n_verified.astype(jnp.float32),
+                "overflow": overflow.astype(jnp.float32)[None],
+            }
+            if vcfg.emit_pairs:
+                if emit == "compact":
+                    out["pairs"] = cpairs  # (spd, pair_cap, 2) int32, -1 padded
+                    out["pair_counts"] = ccounts  # (spd,) int32 TRUE totals
+                else:
+                    out["masks"] = masks  # (spd, M*cap_v, M*cap_w)
+                    out["v_ids"] = fvi
+                    out["w_ids"] = fwi
+            return out
 
-        slots = (fv, fvi, fvo, fw, fwi, fwo, local_cells)
-        if emit == "compact":
-            # One slot at a time: only that slot's (M·cap_v, M·cap_w) mask
-            # is ever live, and only its compacted pairs leave the loop —
-            # under vmap every slot's mask would sit in HBM at once.
-            # The masks are already validity- and de-dup-filtered
-            # (verify_tile -> ref.emit_mask), so compaction just gathers
-            # global ids.
-            def compact_slot(slot):
-                mask, n_ver, n_cnd = verify_cell(*slot)
-                pairs, count = kref.compact_mask(mask, slot[1], slot[4], vcfg.pair_cap)
-                return pairs, count, n_ver, n_cnd
+        def payload(x: Array, xm: Array) -> Array:
+            """Dispatch rows: the raw features, plus — under prune="pivot" — the
+            mapped coordinates as trailing columns (same all_to_all, no second
+            shuffle)."""
+            if prune == "pivot":
+                return jnp.concatenate([x, xm.astype(x.dtype)], axis=1)
+            return x
 
-            cpairs, ccounts, n_verified, n_cand = jax.lax.map(compact_slot, slots)
-            hit_count = ccounts.sum()
+        if cross:
+            def per_shard(arrays, xr: Array, valid_r: Array, ids_r: Array,
+                          xs: Array, valid_s: Array, ids_s: Array):
+                traced = _traced_plan(arrays, metric, delta, p)
+                cells_r, _, v_r, xm_r = _map_assign(traced, xr, valid_r, backend, map_fused)
+                cells_s, member_s, _, xm_s = _map_assign(traced, xs, valid_s, backend, map_fused)
+                v_buf, v_ids, v_own, overflow_v = v_dispatch(
+                    payload(xr, xm_r), ids_r, cells_r, v_r
+                )
+                w_buf, w_ids, w_own, overflow_w = w_dispatch(
+                    payload(xs, xm_s), ids_s, cells_s, member_s
+                )
+                return shuffle_and_verify(
+                    (v_buf, v_ids, v_own), (w_buf, w_ids, w_own),
+                    overflow_v + overflow_w,
+                )
+            in_specs = (P(),) + (P(axis),) * 6
         else:
-            masks, n_verified, n_cand = jax.vmap(verify_cell)(*slots)
-            hit_count = masks.sum()
-        out = {
-            "hits": hit_count.astype(jnp.float32)[None],
-            "verified": n_verified.sum().astype(jnp.float32)[None],
-            "candidates": n_cand.sum().astype(jnp.float32)[None],
-            # Per DISPATCH SLOT (== per cell under contiguous placement); the
-            # driver folds slabs back to cells and devices host-side.
-            "per_cell_verified": n_verified.astype(jnp.float32),
-            "overflow": overflow.astype(jnp.float32)[None],
+            def per_shard(arrays, x: Array, valid: Array, ids: Array):
+                traced = _traced_plan(arrays, metric, delta, p)
+                cells, member, v, xm = _map_assign(traced, x, valid, backend, map_fused)
+                rows = payload(x, xm)
+                v_buf, v_ids, v_own, overflow_v = v_dispatch(rows, ids, cells, v)
+                w_buf, w_ids, w_own, overflow_w = w_dispatch(rows, ids, cells, member)
+                return shuffle_and_verify(
+                    (v_buf, v_ids, v_own), (w_buf, w_ids, w_own),
+                    overflow_v + overflow_w,
+                )
+            in_specs = (P(),) + (P(axis),) * 3
+
+        out_specs = {
+            "hits": P(axis),
+            "verified": P(axis),
+            "candidates": P(axis),
+            "per_cell_verified": P(axis),
+            "overflow": P(axis),
         }
         if vcfg.emit_pairs:
             if emit == "compact":
-                out["pairs"] = cpairs  # (spd, pair_cap, 2) int32, -1 padded
-                out["pair_counts"] = ccounts  # (spd,) int32 TRUE totals
+                out_specs.update({"pairs": P(axis), "pair_counts": P(axis)})
             else:
-                out["masks"] = masks  # (spd, M*cap_v, M*cap_w)
-                out["v_ids"] = fvi
-                out["w_ids"] = fwi
-        return out
+                out_specs.update({"masks": P(axis), "v_ids": P(axis), "w_ids": P(axis)})
 
-    def payload(x: Array, xm: Array) -> Array:
-        """Dispatch rows: the raw features, plus — under prune="pivot" — the
-        mapped coordinates as trailing columns (same all_to_all, no second
-        shuffle)."""
-        if prune == "pivot":
-            return jnp.concatenate([x, xm.astype(x.dtype)], axis=1)
-        return x
+        shmap = jax.shard_map(
+            per_shard,
+            mesh=mesh,
+            in_specs=in_specs,
+            out_specs=out_specs,
+            check_vma=False,
+        )
+        return jax.jit(shmap)
 
-    if cross:
-        def per_shard(xr: Array, valid_r: Array, ids_r: Array,
-                      xs: Array, valid_s: Array, ids_s: Array):
-            cells_r, _, v_r, xm_r = _map_assign(plan, xr, valid_r, backend, map_fused)
-            cells_s, member_s, _, xm_s = _map_assign(plan, xs, valid_s, backend, map_fused)
-            v_buf, v_ids, v_own, overflow_v = v_dispatch(
-                payload(xr, xm_r), ids_r, cells_r, v_r
-            )
-            w_buf, w_ids, w_own, overflow_w = w_dispatch(
-                payload(xs, xm_s), ids_s, cells_s, member_s
-            )
-            return shuffle_and_verify(
-                (v_buf, v_ids, v_own), (w_buf, w_ids, w_own),
-                overflow_v + overflow_w,
-            )
-        in_specs = (P(axis),) * 6
-    else:
-        def per_shard(x: Array, valid: Array, ids: Array):
-            cells, member, v, xm = _map_assign(plan, x, valid, backend, map_fused)
-            rows = payload(x, xm)
-            v_buf, v_ids, v_own, overflow_v = v_dispatch(rows, ids, cells, v)
-            w_buf, w_ids, w_own, overflow_w = w_dispatch(rows, ids, cells, member)
-            return shuffle_and_verify(
-                (v_buf, v_ids, v_own), (w_buf, w_ids, w_own),
-                overflow_v + overflow_w,
-            )
-        in_specs = (P(axis),) * 3
+    stage = _stage(_verify_key(mesh, axis, plan, vcfg, cross, pl), build)
+    return functools.partial(stage, _plan_arrays(plan))
 
-    out_specs = {
-        "hits": P(axis),
-        "verified": P(axis),
-        "candidates": P(axis),
-        "per_cell_verified": P(axis),
-        "overflow": P(axis),
-    }
-    if vcfg.emit_pairs:
-        if emit == "compact":
-            out_specs.update({"pairs": P(axis), "pair_counts": P(axis)})
-        else:
-            out_specs.update({"masks": P(axis), "v_ids": P(axis), "w_ids": P(axis)})
 
-    shmap = jax.shard_map(
-        per_shard,
-        mesh=mesh,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        check_vma=False,
-    )
-    return jax.jit(shmap)
+def _verify_key(mesh: Mesh, axis: str, plan: JoinPlan, vcfg: VerifyConfig, cross: bool,
+                pl: placement_lib.PlacementPlan) -> tuple:
+    """What a verify stage compiles in besides its arguments' shapes."""
+    return ("verify", mesh, axis, plan.metric, plan.p, float(plan.delta),
+            plan.anchors.shape[0], vcfg, cross, _placement_key(pl))
 
 
 # ---------------------------------------------------------------------------
@@ -854,246 +952,286 @@ def distributed_join(
         )
     if s is data:
         s = None  # R = S aliasing: the canonical semantics is the self-join
+    if prune == "window":
+        raise ValueError('distributed_join supports prune="none" | "pivot"')
     cross = s is not None
     backend = kops.resolve_backend(backend, metric, use_kernel)
     M = mesh.shape[axis]
     key = jax.random.PRNGKey(seed)
     n, m = data.shape
-    # Pre-padding host pools, only materialized for the random sampler (the
-    # generative default never moves sample rows off-device).
-    r_host = np.asarray(data) if sampler == "random" else None
-    sharding = NamedSharding(mesh, P(axis))
-    data, valid, ids, _ = _pad_shard_set(jnp.asarray(data), M, sharding)
-    if cross:
-        s_host = np.asarray(s) if sampler == "random" else None
-        s_arr, valid_s, ids_s, n_s = _pad_shard_set(jnp.asarray(s), M, sharding)
-    else:
-        n_s = n
-
     p = p or 2 * M
     p = int(np.ceil(p / M) * M)
+    with tracing.root("dist.join", rows=n, devices=M, p=p):
+        with tracing.span("dist.put"):
+            # Pre-padding host pools, only materialized for the random sampler
+            # (the generative default never moves sample rows off-device).
+            r_host = np.asarray(data) if sampler == "random" else None
+            sharding = NamedSharding(mesh, P(axis))
+            data, valid, ids, _ = _pad_shard_set(jnp.asarray(data), M, sharding)
+            if cross:
+                s_host = np.asarray(s) if sampler == "random" else None
+                s_arr, valid_s, ids_s, n_s = _pad_shard_set(jnp.asarray(s), M, sharding)
+            else:
+                n_s = n
 
-    # ---- sampling phase -----------------------------------------------------
-    stats_fn = make_stage_stats(mesh, axis, t_cells, backend)
-    packets, confs, counts = jax.tree.map(np.asarray, stats_fn(data, valid))
-    if cross:
-        # S's shards are additional "local nodes": pool both sets' packets so
-        # the replicated Gibbs chain samples from the R∪S mixture.
-        pk_s, cf_s, ct_s = jax.tree.map(np.asarray, stats_fn(s_arr, valid_s))
-        packets = np.concatenate([packets, pk_s])
-        confs = np.concatenate([confs, cf_s])
-        counts = np.concatenate([counts, ct_s])
-        # All-padding shards (|S| < M, or empty S) carry no distribution.
-        keep = counts > 0
-        packets, confs, counts = packets[keep], confs[keep], counts[keep]
+        # ---- sampling phase -------------------------------------------------
+        with tracing.span("dist.sample"):
+            stats_fn = make_stage_stats(mesh, axis, t_cells, backend)
+            packets, confs, counts = jax.tree.map(np.asarray, stats_fn(data, valid))
+            if cross:
+                # S's shards are additional "local nodes": pool both sets'
+                # packets so the replicated Gibbs chain samples from the R∪S
+                # mixture.
+                pk_s, cf_s, ct_s = jax.tree.map(np.asarray, stats_fn(s_arr, valid_s))
+                packets = np.concatenate([packets, pk_s])
+                confs = np.concatenate([confs, cf_s])
+                counts = np.concatenate([counts, ct_s])
+                # All-padding shards (|S| < M, or empty S) carry no distribution.
+                keep = counts > 0
+                packets, confs, counts = packets[keep], confs[keep], counts[keep]
 
-    k_gibbs, k_anchor = jax.random.split(key)
-    accept_rate = 1.0
-    if sampler == "generative":
-        conf_n = np.clip(confs / max(confs.max(), 1e-6), 1e-3, 1.0)
-        c_min = float(np.clip(conf_n.min(), 0.05, 1.0))
-        length = int(np.ceil(k / c_min * 1.5)) + 8
-        pivots, acc = gibbs_from_packets(
-            k_gibbs, jnp.asarray(packets), jnp.asarray(confs), jnp.asarray(counts), k, length
-        )
-        accept_rate = float(acc)
-        if accept_rate <= 0.0:
-            warnings.warn(
-                "gibbs_from_packets accepted no draws (all node confidences "
-                "≈ 0); pivots fall back to raw chain draws", stacklevel=2,
-            )
-    elif sampler == "random":
-        pool = np.concatenate([r_host, s_host]) if cross else r_host
-        idx = jax.random.choice(
-            k_gibbs, pool.shape[0], shape=(min(k, pool.shape[0]),), replace=False
-        )
-        pivots = jnp.asarray(pool)[idx]
-    else:
-        raise ValueError(f"distributed sampler must be generative|random, got {sampler!r}")
+            k_gibbs, k_anchor = jax.random.split(key)
+            accept_rate = 1.0
+            if sampler == "generative":
+                conf_n = np.clip(confs / max(confs.max(), 1e-6), 1e-3, 1.0)
+                c_min = float(np.clip(conf_n.min(), 0.05, 1.0))
+                length = int(np.ceil(k / c_min * 1.5)) + 8
+                pivots, acc = gibbs_from_packets(
+                    k_gibbs, jnp.asarray(packets), jnp.asarray(confs), jnp.asarray(counts),
+                    k, length,
+                )
+                accept_rate = float(acc)
+                if accept_rate <= 0.0:
+                    warnings.warn(
+                        "gibbs_from_packets accepted no draws (all node confidences "
+                        "≈ 0); pivots fall back to raw chain draws", stacklevel=2,
+                    )
+            elif sampler == "random":
+                pool = np.concatenate([r_host, s_host]) if cross else r_host
+                idx = jax.random.choice(
+                    k_gibbs, pool.shape[0], shape=(min(k, pool.shape[0]),), replace=False
+                )
+                pivots = jnp.asarray(pool)[idx]
+            else:
+                raise ValueError(
+                    f"distributed sampler must be generative|random, got {sampler!r}"
+                )
 
-    # ---- control plane ------------------------------------------------------
-    plan = build_join_plan(
-        k_anchor,
-        pivots,
-        delta=delta,
-        metric=metric,
-        p=p,
-        n_dims=n_dims,
-        partitioner=partitioner,
-        seed=seed,
-    )
-
-    # ---- counting pass + capacity planning ----------------------------------
-    # V capacities always come from R's kernel counts; W capacities from the
-    # W-side set's whole counts (S when cross, R itself when self).
-    counts_fn = make_stage_counts(mesh, axis, plan, backend, fused=map_fused)
-    v_cnt, w_cnt, cell_lo, cell_hi = jax.tree.map(
-        np.asarray, counts_fn(data, valid)
-    )  # (M, p[, n])
-    if cross and not tighten:
-        # With tighten the S recount below supersedes this pass entirely.
-        _, w_cnt, _, _ = jax.tree.map(np.asarray, counts_fn(s_arr, valid_s))
-
-    if tighten:
-        # H3-it1: whole box := delta-expanded MBB of the cell's members.
-        # Kernel-cell MBBs come from R (the V side) in both modes: Lemma 4
-        # puts every within-δ W partner inside the δ-expanded R MBB.
-        glo = cell_lo.min(0)  # (p, n) across shards
-        ghi = cell_hi.max(0)
-        empty = glo > ghi  # no members anywhere
-        glo = np.where(empty, partition.BIG, glo)
-        ghi = np.where(empty, -partition.BIG, ghi)
-        plan = dataclasses.replace(
-            plan,
-            whole_lo=jnp.asarray(glo - plan.delta, jnp.float32),
-            whole_hi=jnp.asarray(ghi + plan.delta, jnp.float32),
-        )
-        # W counts changed: one cheap recount against the tightened plan
-        # (kernel assignment — the V counts — is unaffected by whole boxes).
-        counts_fn = make_stage_counts(mesh, axis, plan, backend, fused=map_fused)
-        if cross:
-            _, w_cnt, _, _ = jax.tree.map(np.asarray, counts_fn(s_arr, valid_s))
-        else:
-            v_cnt, w_cnt, _, _ = jax.tree.map(np.asarray, counts_fn(data, valid))
-
-    # Cost-model prediction from the pivots alone (what a single-pass system
-    # would have to provision) — reported for the EXPERIMENTS Table 3 story,
-    # and the input of the placement planner below.
-    piv_mapped = kops.pairdist(pivots, plan.anchors, metric, backend=backend)
-    piv_cells = partition.assign_kernel(
-        partition.PartitionPlan(plan.kernel_lo, plan.kernel_hi, plan.whole_lo, plan.whole_hi, delta),
-        piv_mapped,
-    )
-    piv_member = partition.whole_membership(
-        partition.PartitionPlan(plan.kernel_lo, plan.kernel_hi, plan.whole_lo, plan.whole_hi, delta),
-        piv_mapped,
-    )
-    if prune == "window":
-        raise ValueError('distributed_join supports prune="none" | "pivot"')
-    prune_resolved = verify_lib.resolve_prune(prune, metric, True)
-    delta_bound = (
-        verify_lib.prune_band(delta, metric, data, s_arr if cross else None)
-        if prune_resolved == "pivot"
-        else None
-    )
-
-    # ---- placement plan (cost-model-guided reduce placement) ----------------
-    # Predicted per-cell verification loads (Eq. 33 costs from the pivot
-    # sample, survival-adjusted — the fraction of candidate pivot pairs
-    # surviving the L∞ bound forecasts the post-filter exact-evaluation
-    # fraction) drive the cell→device plan; the EXACT counting-pass counts,
-    # re-laid-out per planned slot, size the static capacities — so placement
-    # never risks overflow, it only moves work and shrinks the worst-slot
-    # capacity. In R×S mode the W estimate scales with |S|, not |R|; caveat:
-    # the pivots approximate the POOLED R∪S mixture, so when the two
-    # distributions diverge the estimates are biased toward R's geography —
-    # only the exact-count capacities govern correctness; predicted_cap_w is
-    # the "single-pass provisioning" story metric.
-    cell_loads, predicted_survival, _, w_est = placement_lib.planner_inputs(
-        np.asarray(piv_mapped), np.asarray(piv_cells), np.asarray(piv_member),
-        n, n_s, delta, prune_resolved == "pivot",
-    )
-    predicted_cap_w = cost_model.predict_capacity(w_est, M, slack=1.25)
-    pl = placement_lib.plan_placement(cell_loads, M, strategy=placement)
-    v_slot, w_slot = placement_lib.slot_exact_counts(pl, v_cnt, w_cnt)
-    exact_cap_v = max(int(v_slot.max(initial=0)), 1)
-    exact_cap_w = max(int(w_slot.max(initial=0)), 1)
-    cap_v = int(np.ceil(exact_cap_v * capacity_slack))
-    cap_w = int(np.ceil(exact_cap_w * capacity_slack))
-    cap_saved = placement_lib.capacity_saved_bytes(
-        pl, v_cnt, w_cnt,
-        placement_lib.dispatch_row_bytes(m, n_dims, prune_resolved == "pivot"),
-        slack=capacity_slack,
-    )
-
-    # ---- dispatch + verify ---------------------------------------------------
-    # Compact emission: static per-slot pair capacity from the cost model's
-    # survival estimate (an overestimate of the hit rate — the safe
-    # direction), on the same quarter-pow2 bucket ladder as the engine.
-    emit_resolved = verify_lib.resolve_emit(emit, metric) if emit_pairs else "mask"
-    slot_area = max(int(v_slot.max(initial=0)) * int(w_slot.max(initial=0)), 1)
-    pair_cap = 0
-    if emit_resolved == "compact":
-        est = int(slot_area * min(predicted_survival * verify_lib.EMIT_SLACK, 1.0))
-        pair_cap = verify_lib.bucket_size(est + verify_lib._EMIT_FLOOR, slot_area)
-    vcfg = VerifyConfig(
-        cap_v=cap_v, cap_w=cap_w, emit_pairs=emit_pairs, backend=backend,
-        prune=prune, delta_bound=delta_bound, map_fused=map_fused,
-        emit=emit_resolved, pair_cap=pair_cap,
-    )
-    n_overflow_retries = 0
-    for attempt in range(verify_lib._MAX_OVERFLOW_RETRIES + 2):
-        verify_fn = make_stage_verify(mesh, axis, plan, vcfg, cross=cross, pl=pl)
-        out = (
-            verify_fn(data, valid, ids, s_arr, valid_s, ids_s)
-            if cross
-            else verify_fn(data, valid, ids)
-        )
-        if vcfg.emit != "compact":
-            break
-        max_count = int(np.asarray(out["pair_counts"]).max(initial=0))
-        if max_count <= vcfg.pair_cap:
-            break
-        # Overflow sentinel: the counts are TRUE totals, so one re-size is
-        # exact; a bounded ladder guards monkeypatched/adversarial sizing,
-        # then the mask path — emitted pairs are identical on every rung.
-        n_overflow_retries += 1
-        if attempt >= verify_lib._MAX_OVERFLOW_RETRIES:
-            vcfg = dataclasses.replace(vcfg, emit="mask", pair_cap=0)
-        else:
-            vcfg = dataclasses.replace(
-                vcfg,
-                pair_cap=verify_lib.bucket_size(
-                    max(max_count, 2 * vcfg.pair_cap), slot_area
-                ),
+        with tracing.span("dist.plan") as plan_span:
+            # ---- control plane ----------------------------------------------
+            plan = build_join_plan(
+                k_anchor,
+                pivots,
+                delta=delta,
+                metric=metric,
+                p=p,
+                n_dims=n_dims,
+                partitioner=partitioner,
+                seed=seed,
             )
 
-    # Per-slot telemetry (dispatch order) folds back to cells and devices.
-    per_slot = np.asarray(out["per_cell_verified"]).reshape(-1)  # (n_slots,)
-    cod = pl.cell_of_dispatch
-    per_cell = np.zeros(p, np.float32)
-    np.add.at(per_cell, cod[cod >= 0], per_slot[cod >= 0])
-    device_loads = per_slot.reshape(M, -1).sum(1)
-    actual_v = int(v_slot.sum())  # dispatched rows (W counts slab replicas)
-    actual_w = int(w_slot.sum())
-    padding = (pl.n_slots * M * (cap_v + cap_w)) / max(actual_v + actual_w, 1)
+            # ---- counting pass + capacity planning --------------------------
+            # V capacities always come from R's kernel counts; W capacities
+            # from the W-side set's whole counts (S when cross, R itself when
+            # self).
+            counts_fn = make_stage_counts(mesh, axis, plan, backend, fused=map_fused)
+            v_cnt, w_cnt, cell_lo, cell_hi = jax.tree.map(
+                np.asarray, counts_fn(data, valid)
+            )  # (M, p[, n])
+            if cross and not tighten:
+                # With tighten the S recount below supersedes this pass entirely.
+                _, w_cnt, _, _ = jax.tree.map(np.asarray, counts_fn(s_arr, valid_s))
 
-    pairs = None
-    if emit_pairs and vcfg.emit == "compact":
-        # (M*spd, pair_cap, 2) compacted global-id pairs + per-slot counts;
-        # rows past each slot's count are -1 padding (or, pre-retry,
-        # unspecified) and are sliced off here.
-        cpairs = np.asarray(out["pairs"]).reshape(-1, vcfg.pair_cap, 2)
-        ccounts = np.asarray(out["pair_counts"]).reshape(-1)
-        rows = [cp[:c] for cp, c in zip(cpairs, ccounts) if c]
-        if rows:
-            pr = np.concatenate(rows).astype(np.int64)
-            if not cross:
-                pr = np.stack([pr.min(axis=1), pr.max(axis=1)], 1)
-            pairs = np.unique(pr, axis=0)
-        else:
-            pairs = np.zeros((0, 2), np.int64)
-    elif emit_pairs:
-        masks = np.asarray(out["masks"])  # (M*spd, Mcap_v, Mcap_w) flattened over devices
-        v_ids = np.asarray(out["v_ids"]).reshape(masks.shape[0], -1)
-        w_ids = np.asarray(out["w_ids"]).reshape(masks.shape[0], -1)
-        masks = masks.reshape(masks.shape[0], v_ids.shape[1], w_ids.shape[1])
-        cell, vi, wi = np.nonzero(masks)
-        gi = v_ids[cell, vi]
-        gj = w_ids[cell, wi]
-        if cross:
-            pr = np.stack([gi, gj], 1)  # columns index different sets
-        else:
-            pr = np.stack([np.minimum(gi, gj), np.maximum(gi, gj)], 1)
-        pairs = np.unique(pr, axis=0).astype(np.int64) if pr.size else np.zeros((0, 2), np.int64)
+            if tighten:
+                # H3-it1: whole box := delta-expanded MBB of the cell's members.
+                # Kernel-cell MBBs come from R (the V side) in both modes:
+                # Lemma 4 puts every within-δ W partner inside the δ-expanded
+                # R MBB.
+                glo = cell_lo.min(0)  # (p, n) across shards
+                ghi = cell_hi.max(0)
+                empty = glo > ghi  # no members anywhere
+                glo = np.where(empty, partition.BIG, glo)
+                ghi = np.where(empty, -partition.BIG, ghi)
+                plan = dataclasses.replace(
+                    plan,
+                    whole_lo=jnp.asarray(glo - plan.delta, jnp.float32),
+                    whole_hi=jnp.asarray(ghi + plan.delta, jnp.float32),
+                )
+                # W counts changed: one cheap recount against the tightened
+                # plan (kernel assignment — the V counts — is unaffected by
+                # whole boxes). Same shapes, so the same compiled stage.
+                counts_fn = make_stage_counts(mesh, axis, plan, backend, fused=map_fused)
+                if cross:
+                    _, w_cnt, _, _ = jax.tree.map(np.asarray, counts_fn(s_arr, valid_s))
+                else:
+                    v_cnt, w_cnt, _, _ = jax.tree.map(np.asarray, counts_fn(data, valid))
 
-    n_verifications = int(np.asarray(out["verified"]).sum())
-    n_candidates = int(np.asarray(out["candidates"]).sum())
+            # Cost-model prediction from the pivots alone (what a single-pass
+            # system would have to provision) — reported for the EXPERIMENTS
+            # Table 3 story, and the input of the placement planner below.
+            piv_plan = partition.PartitionPlan(
+                plan.kernel_lo, plan.kernel_hi, plan.whole_lo, plan.whole_hi, delta
+            )
+            piv_mapped = kops.pairdist(pivots, plan.anchors, metric, backend=backend)
+            piv_cells = partition.assign_kernel(piv_plan, piv_mapped)
+            piv_member = partition.whole_membership(piv_plan, piv_mapped)
+            prune_resolved = verify_lib.resolve_prune(prune, metric, True)
+            delta_bound = (
+                verify_lib.prune_band(delta, metric, data, s_arr if cross else None)
+                if prune_resolved == "pivot"
+                else None
+            )
+
+            # ---- placement plan (cost-model-guided reduce placement) --------
+            # Predicted per-cell verification loads (Eq. 33 costs from the
+            # pivot sample, survival-adjusted — the fraction of candidate
+            # pivot pairs surviving the L∞ bound forecasts the post-filter
+            # exact-evaluation fraction) drive the cell→device plan; the
+            # EXACT counting-pass counts, re-laid-out per planned slot, size
+            # the static capacities — so placement never risks overflow, it
+            # only moves work and shrinks the worst-slot capacity. In R×S
+            # mode the W estimate scales with |S|, not |R|; caveat: the
+            # pivots approximate the POOLED R∪S mixture, so when the two
+            # distributions diverge the estimates are biased toward R's
+            # geography — only the exact-count capacities govern
+            # correctness; predicted_cap_w is the "single-pass provisioning"
+            # story metric.
+            cell_loads, predicted_survival, _, w_est = placement_lib.planner_inputs(
+                np.asarray(piv_mapped), np.asarray(piv_cells), np.asarray(piv_member),
+                n, n_s, delta, prune_resolved == "pivot",
+            )
+            predicted_cap_w = cost_model.predict_capacity(w_est, M, slack=1.25)
+            pl = placement_lib.plan_placement(cell_loads, M, strategy=placement)
+            v_slot, w_slot = placement_lib.slot_exact_counts(pl, v_cnt, w_cnt)
+            exact_cap_v = max(int(v_slot.max(initial=0)), 1)
+            exact_cap_w = max(int(w_slot.max(initial=0)), 1)
+            cap_v = int(np.ceil(exact_cap_v * capacity_slack))
+            cap_w = int(np.ceil(exact_cap_w * capacity_slack))
+            cap_saved = placement_lib.capacity_saved_bytes(
+                pl, v_cnt, w_cnt,
+                placement_lib.dispatch_row_bytes(m, n_dims, prune_resolved == "pivot"),
+                slack=capacity_slack,
+            )
+
+            # Compact emission: static per-slot pair capacity from the cost
+            # model's survival estimate (an overestimate of the hit rate — the
+            # safe direction), on the same quarter-pow2 bucket ladder as the
+            # engine.
+            emit_resolved = verify_lib.resolve_emit(emit, metric) if emit_pairs else "mask"
+            vcfg = VerifyConfig(
+                cap_v=cap_v, cap_w=cap_w, emit_pairs=emit_pairs, backend=backend,
+                prune=prune, delta_bound=delta_bound, map_fused=map_fused,
+                emit=emit_resolved,
+            )
+            fitted_key = ("pair_cap",) + _verify_key(mesh, axis, plan, vcfg, cross, pl)
+            slot_area = max(int(v_slot.max(initial=0)) * int(w_slot.max(initial=0)), 1)
+            if emit_resolved == "compact":
+                est = int(slot_area * min(predicted_survival * verify_lib.EMIT_SLACK, 1.0))
+                vcfg = dataclasses.replace(vcfg, pair_cap=min(
+                    verify_lib.bucket_size(est + verify_lib._EMIT_FLOOR, slot_area),
+                    _STAGES.get(fitted_key, _FIRST_PAIR_CAP),
+                ))
+            actual_v = int(v_slot.sum())  # dispatched rows (W counts slab replicas)
+            actual_w = int(w_slot.sum())
+            # Each slot verifies its V rows against its W rows, so the exact
+            # counts give every device's load before the stage runs.
+            planned_loads = np.bincount(
+                pl.device_of_slot, weights=v_slot.sum(0) * w_slot.sum(0), minlength=M
+            )
+            plan_span.add(
+                cap_v=cap_v, cap_w=cap_w, pair_cap=vcfg.pair_cap,
+                duplication=float(actual_w / max(n_s, 1)),
+                makespan_ratio=float(planned_loads.max() / max(planned_loads.mean(), 1e-9)),
+            )
+
+        # ---- dispatch + verify ----------------------------------------------
+        n_overflow_retries = 0
+        with tracing.span("dist.stage") as stage_span:
+            for attempt in range(verify_lib._MAX_OVERFLOW_RETRIES + 2):
+                verify_fn = make_stage_verify(mesh, axis, plan, vcfg, cross=cross, pl=pl)
+                out = (
+                    verify_fn(data, valid, ids, s_arr, valid_s, ids_s)
+                    if cross
+                    else verify_fn(data, valid, ids)
+                )
+                if vcfg.emit != "compact":
+                    jax.block_until_ready(out)
+                    break
+                max_count = int(np.asarray(out["pair_counts"]).max(initial=0))
+                if max_count <= vcfg.pair_cap:
+                    break
+                # Overflow sentinel: the counts are TRUE totals, so one
+                # re-size is exact; a bounded ladder guards
+                # monkeypatched/adversarial sizing, then the mask path —
+                # emitted pairs are identical on every rung.
+                n_overflow_retries += 1
+                if attempt >= verify_lib._MAX_OVERFLOW_RETRIES:
+                    vcfg = dataclasses.replace(vcfg, emit="mask", pair_cap=0)
+                else:
+                    vcfg = dataclasses.replace(
+                        vcfg,
+                        pair_cap=verify_lib.bucket_size(
+                            max(max_count, 2 * vcfg.pair_cap), slot_area
+                        ),
+                    )
+            if n_overflow_retries and vcfg.emit == "compact":
+                _remember(fitted_key, max(vcfg.pair_cap, _STAGES.get(fitted_key, 0)))
+            stage_span.add(overflow_retries=n_overflow_retries)
+
+        with tracing.span("dist.readback") as readback:
+            out = jax.device_get(out)
+            n_hits = int(out["hits"].sum())
+            readback.add(n_hits=n_hits)
+
+        # Per-slot telemetry (dispatch order) folds back to cells and devices.
+        per_slot = out["per_cell_verified"].reshape(-1)  # (n_slots,)
+        cod = pl.cell_of_dispatch
+        per_cell = np.zeros(p, np.float32)
+        np.add.at(per_cell, cod[cod >= 0], per_slot[cod >= 0])
+        device_loads = per_slot.reshape(M, -1).sum(1)
+        padding = (pl.n_slots * M * (cap_v + cap_w)) / max(actual_v + actual_w, 1)
+
+        pairs = None
+        with tracing.span("dist.merge") as merge:
+            if emit_pairs and vcfg.emit == "compact":
+                # (M*spd, pair_cap, 2) compacted global-id pairs + per-slot
+                # counts; rows past each slot's count are -1 padding (or,
+                # pre-retry, unspecified) and are sliced off here.
+                cpairs = out["pairs"].reshape(-1, vcfg.pair_cap, 2)
+                ccounts = out["pair_counts"].reshape(-1)
+                rows = [cp[:c] for cp, c in zip(cpairs, ccounts) if c]
+                if rows:
+                    pr = np.concatenate(rows).astype(np.int64)
+                    if not cross:
+                        pr = np.stack([pr.min(axis=1), pr.max(axis=1)], 1)
+                    pairs = np.unique(pr, axis=0)
+                else:
+                    pairs = np.zeros((0, 2), np.int64)
+            elif emit_pairs:
+                masks = out["masks"]  # (M*spd, Mcap_v, Mcap_w) flattened over devices
+                v_ids = out["v_ids"].reshape(masks.shape[0], -1)
+                w_ids = out["w_ids"].reshape(masks.shape[0], -1)
+                masks = masks.reshape(masks.shape[0], v_ids.shape[1], w_ids.shape[1])
+                cell, vi, wi = np.nonzero(masks)
+                gi = v_ids[cell, vi]
+                gj = w_ids[cell, wi]
+                if cross:
+                    pr = np.stack([gi, gj], 1)  # columns index different sets
+                else:
+                    pr = np.stack([np.minimum(gi, gj), np.maximum(gi, gj)], 1)
+                pairs = (
+                    np.unique(pr, axis=0).astype(np.int64) if pr.size
+                    else np.zeros((0, 2), np.int64)
+                )
+            merge.add(n_pairs=0 if pairs is None else int(pairs.shape[0]))
+
+    n_verifications = int(out["verified"].sum())
+    n_candidates = int(out["candidates"].sum())
     return DistJoinResult(
-        n_hits=int(out["hits"].sum()) if np.asarray(out["hits"]).ndim else int(out["hits"]),
+        n_hits=n_hits,
         n_verifications=n_verifications,
         per_cell_verified=per_cell,
-        overflow=int(np.asarray(out["overflow"]).sum()),
+        overflow=int(out["overflow"].sum()),
         capacity_padding=float(padding),
         predicted_cap_w=int(predicted_cap_w),
         exact_cap_w=exact_cap_w,

@@ -22,6 +22,7 @@ the verify phase on TPU.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -46,6 +47,25 @@ class SpaceMap:
     def __call__(self, x: Array) -> Array:
         """(N, m) objects → (N, n) target-space coordinates."""
         return distances.pairwise(x, self.anchors, self.metric)
+
+
+@functools.partial(jax.jit, static_argnames=("n",))
+def _farthest_first(d: Array, first: Array, n: int) -> Array:
+    """The ``n`` pivot indices of a farthest-first traversal over the
+    (k, k) distances ``d`` from pivot ``first``; compiled once per shape,
+    so repeated selections reuse the program."""
+
+    def body(carry, _):
+        chosen_mask, min_dist = carry
+        # Next anchor: farthest pivot from the chosen set.
+        nxt = jnp.argmax(jnp.where(chosen_mask, -jnp.inf, min_dist))
+        chosen_mask = chosen_mask.at[nxt].set(True)
+        min_dist = jnp.minimum(min_dist, d[nxt])
+        return (chosen_mask, min_dist), nxt
+
+    mask0 = jnp.zeros((d.shape[0],), bool).at[first].set(True)
+    (_, _), rest = jax.lax.scan(body, (mask0, d[first]), None, length=n - 1)
+    return jnp.concatenate([first[None], rest])
 
 
 def select_anchors(
@@ -94,18 +114,7 @@ def select_anchors(
     n_distinct = int(k - twin.sum())
     n_fft = min(n, n_distinct)
     first = jax.random.randint(key, (), 0, k)
-
-    def body(carry, _):
-        chosen_mask, min_dist = carry
-        # Next anchor: farthest pivot from the chosen set.
-        nxt = jnp.argmax(jnp.where(chosen_mask, -jnp.inf, min_dist))
-        chosen_mask = chosen_mask.at[nxt].set(True)
-        min_dist = jnp.minimum(min_dist, d[nxt])
-        return (chosen_mask, min_dist), nxt
-
-    mask0 = jnp.zeros((k,), bool).at[first].set(True)
-    (_, _), rest = jax.lax.scan(body, (mask0, d[first]), None, length=n_fft - 1)
-    idx = jnp.concatenate([first[None], rest])
+    idx = _farthest_first(d, first, n_fft)
     if n_fft < n:
         fill = jax.random.choice(
             jax.random.fold_in(key, 1), k, shape=(n - n_fft,), replace=False

@@ -8,8 +8,9 @@ import textwrap
 
 import pytest
 
-def _run(code: str) -> dict:
-    prog = "import os\nos.environ['XLA_FLAGS']='--xla_force_host_platform_device_count=8'\n" + textwrap.dedent(code)
+def _run(code: str, devices: int = 8) -> dict:
+    prog = (f"import os\nos.environ['XLA_FLAGS']='--xla_force_host_platform_device_count={devices}'\n"
+            + textwrap.dedent(code))
     out = subprocess.run(
         [sys.executable, "-c", prog],
         capture_output=True, text=True, timeout=900,
@@ -46,6 +47,84 @@ def test_distributed_join_exact_both_samplers():
     for sampler, r in res.items():
         assert r["exact"], (sampler, r)
         assert r["overflow"] == 0
+
+
+# Near-duplicate 0/1 codes and a δ² halfway between two integers, so that
+# every engine's distances land on the same side of δ.
+CODES = """
+import json, numpy as np, jax
+from jax import monitoring
+from repro.core import distances, distributed, spjoin
+from repro.launch import mesh as mesh_lib
+rng = np.random.default_rng(0)
+centres = rng.random((16, 64)) > 0.5
+x = (centres[rng.integers(0, 16, 800)] ^ (rng.random((800, 64)) < 0.05)).astype(np.float32)
+delta = float(np.sqrt(4.5))
+mesh = mesh_lib.make_host_mesh(4)
+kw = dict(mesh=mesh, delta=delta, metric="l2", p=16, k=128, emit_pairs=True, emit="compact")
+compiled = []
+monitoring.register_event_duration_secs_listener(
+    lambda event, _s, **_k: compiled.append(event)
+    if event == "/jax/core/compile/backend_compile_duration" else None)
+"""
+
+
+def _run_codes(code: str) -> dict:
+    """``code`` on four devices, after ``CODES`` has set up the codes."""
+    return _run(CODES + textwrap.dedent(code), devices=4)
+
+
+def test_distributed_join_compact_4dev_matches_brute_force_and_spjoin():
+    res = _run_codes("""
+    r = distributed.distributed_join(x, **kw)
+    truth = np.argwhere(np.asarray(distances.brute_force_join(x, delta, "l2")))
+    host = spjoin.join(x, spjoin.JoinConfig(delta=delta, metric="l2", p=16, k=128)).pairs
+    print(json.dumps(dict(
+        n=int(r.pairs.shape[0]), emit=r.emit, devices=len(r.device_rows),
+        brute=r.pairs.tobytes() == truth.astype(np.int64).tobytes(),
+        spjoin=r.pairs.tobytes() == np.asarray(host, np.int64).tobytes())))
+    """)
+    assert res["brute"] and res["spjoin"], res
+    assert res["n"] > 0 and res["emit"] == "compact" and res["devices"] == 4
+
+
+def test_second_distributed_join_compiles_nothing():
+    """The join's stages are kept by their static configuration: a second
+    join of the same shapes compiles (or loads) no program, and returns the
+    same pairs."""
+    res = _run_codes("""
+    first = distributed.distributed_join(x, **kw)
+    n_first = len(compiled)
+    second = distributed.distributed_join(x, **kw)
+    n_second = len(compiled) - n_first
+    held = distributed.clear_join_stages()
+    third = distributed.distributed_join(x, **kw)
+    print(json.dumps(dict(n_first=n_first, n_second=n_second, held=held,
+                          n_third=len(compiled) - n_first - n_second,
+                          same=first.pairs.tobytes() == second.pairs.tobytes() == third.pairs.tobytes())))
+    """)
+    assert res["n_first"] > 0 and res["n_second"] == 0, res
+    # stats, counts and verify; once cleared, each is built and compiled again
+    assert res["held"] == res["n_third"] == 3 and res["same"], res
+
+
+def test_fitted_pair_capacity_is_kept_for_the_next_join():
+    """A first pair capacity too small for the hits reruns the stage at a
+    capacity sized from the exact count; the next join of the configuration
+    starts there, so it neither reruns nor compiles."""
+    res = _run_codes("""
+    distributed._FIRST_PAIR_CAP = 8
+    first = distributed.distributed_join(x, **kw)
+    n_first = len(compiled)
+    second = distributed.distributed_join(x, **kw)
+    n_second = len(compiled) - n_first
+    truth = np.argwhere(np.asarray(distances.brute_force_join(x, delta, "l2")))
+    print(json.dumps(dict(
+        retries=[first.n_overflow_retries, second.n_overflow_retries], n_second=n_second,
+        exact=first.pairs.tobytes() == second.pairs.tobytes() == truth.astype(np.int64).tobytes())))
+    """)
+    assert res["retries"][0] >= 1 and res["retries"][1] == 0, res
+    assert res["n_second"] == 0 and res["exact"], res
 
 
 @pytest.mark.slow

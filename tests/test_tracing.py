@@ -8,14 +8,15 @@ import jax
 import numpy as np
 import pytest
 
+from repro.core import distributed, spjoin, tracing
 from repro.core import index as index_lib
-from repro.core import spjoin, tracing
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
-from bench import program_spans  # noqa: E402
+from bench import dist_spans, program_spans  # noqa: E402
 
 JOIN_CHILDREN = ["spjoin.sample", "spjoin.map", "spjoin.reduce", "spjoin.report"]
 SERVE_CHILDREN = ["serve.put", "serve.route", "serve.stage", "serve.readback", "serve.unpack"]
+DIST_CHILDREN = ["dist.put", "dist.sample", "dist.plan", "dist.stage", "dist.readback", "dist.merge"]
 
 
 def _bits(rng, n, m=16):
@@ -157,3 +158,29 @@ def test_serve_batch_rerun_on_pair_overflow(rng, tmp_path):
     n_hits = kids[-1].counts["n_hits"]
     assert caps[0] == 2 < n_hits <= caps[1] == didx._pair_cap
     assert kids[-1].counts["n_pairs"] == pairs.shape[0]
+
+
+@pytest.mark.parametrize("emit", ["mask", "compact"])
+def test_distributed_join_span_tree_and_counters(rng, tmp_path, emit):
+    x = _bits(rng, 400)
+    mesh = jax.make_mesh((1,), ("data",))
+    kw = dict(mesh=mesh, delta=float(np.sqrt(4.5)), metric="l2", p=8, k=128,
+              emit_pairs=True, emit=emit)
+    with jax.profiler.trace(str(tmp_path)):
+        res = distributed.distributed_join(x, **kw)
+    spans = dist_spans.read_dir(tmp_path)
+    roots = [i for i, s in enumerate(spans.spans) if s.parent == -1]
+    assert [spans.spans[i].name for i in roots] == ["dist.join"]
+    root = spans.spans[roots[0]]
+    assert set(root.counts) == {"request", "rows", "devices", "p"}
+    assert (root.counts["rows"], root.counts["devices"], root.counts["p"]) == (400, 1, 8)
+    assert _names(spans, roots[0]) == DIST_CHILDREN
+    (plan,) = spans.named("dist.plan")
+    assert set(plan.counts) == {"cap_v", "cap_w", "pair_cap", "duplication", "makespan_ratio"}
+    assert plan.counts["duplication"] == pytest.approx(res.duplication)
+    assert plan.counts["makespan_ratio"] == pytest.approx(res.makespan_ratio)
+    assert (plan.counts["pair_cap"] > 0) == (emit == "compact")
+    assert spans.named("dist.stage")[0].counts == {"overflow_retries": res.n_overflow_retries}
+    assert spans.named("dist.readback")[0].counts == {"n_hits": res.n_hits}
+    assert spans.named("dist.merge")[0].counts == {"n_pairs": res.pairs.shape[0]}
+    assert res.pairs.shape[0] > 0
